@@ -32,10 +32,10 @@ reporting a speedup for wrong numbers.
 
 Async-collective overlap flags: the TPU set maxtext ships (async
 all-gather fusion + compute/collective overlap) is stamped into every
-point's config as `tpu_async_flags`; this CPU-hosted XLA build rejects
-them as unknown flags, so off-TPU the env applies only the host device
-count and `applied_async_flags` records False.  On a TPU host the
-driver exports them via LIBTPU_INIT_ARGS.
+point's config as `tpu_async_flags`.  Each worker exports them via
+LIBTPU_INIT_ARGS before its backend starts; only a TPU backend loads
+libtpu and reads them, so `applied_async_flags` records whether the
+worker's platform was a TPU.
 """
 from __future__ import annotations
 
@@ -46,9 +46,8 @@ import subprocess
 import sys
 import time
 
-# The overlap flag set from maxtext's sweep driver (TPU-only: XLA's CPU
-# flag parser hard-fails on unknown flags, so these are exported only
-# when the worker platform is a TPU).
+# The overlap flag set from maxtext's sweep script (TPU-only: they ride
+# LIBTPU_INIT_ARGS, never XLA_FLAGS, whose CPU parser rejects them).
 TPU_ASYNC_FLAGS = (
     "--xla_tpu_enable_async_collective_fusion=true "
     "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true "
@@ -79,8 +78,6 @@ def _worker_env(tp: int) -> dict:
     prev = " ".join(f for f in prev.split()
                     if "--xla_force_host_platform_device_count" not in f)
     env["XLA_FLAGS"] = " ".join([prev] + flags).strip()
-    if env.get("JAX_PLATFORMS", "cpu") not in ("cpu", ""):
-        env["LIBTPU_INIT_ARGS"] = TPU_ASYNC_FLAGS
     return env
 
 
@@ -136,10 +133,18 @@ def _timeit(fn, n: int) -> float:
 def main_worker(args) -> int:
     from functools import partial
 
+    # libtpu reads LIBTPU_INIT_ARGS once, when a TPU backend starts in
+    # this process; a CPU worker never loads libtpu, so the flags take
+    # effect exactly when the worker's own platform is a TPU.
+    os.environ["LIBTPU_INIT_ARGS"] = " ".join(
+        [os.environ.get("LIBTPU_INIT_ARGS", ""), TPU_ASYNC_FLAGS]).strip()
+
+    # JAX is imported here and nowhere else in this module: the parent
+    # must stay off JAX, because a process that has touched JAX holds
+    # the accelerator and the worker it spawns could not reach it.
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.configs.base import QuantConfig
@@ -174,10 +179,10 @@ def main_worker(args) -> int:
 
     mode_us = {}
     for mode in ("gather", "ring"):
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             partial(shard_ops.sharded_dequant_matmul, fmt=vp, mode=mode),
             mesh=mesh, in_specs=(P(), P(None, "model")), out_specs=P(),
-            check_rep=False))
+            check_vma=False))
         y = np.asarray(fn(x, w_pk))
         assert np.array_equal(y, y_ref), \
             f"mm {mode} mode lost bit parity at tp={tp} B={B} K={K} N={N}"
@@ -209,12 +214,12 @@ def main_worker(args) -> int:
     emit(f"sweep_attn_single_tp{tp}_B{B}_S{S}", us_attn,
          f"KV={KV};dh={dh};tp=1")
 
-    sh_attn = jax.jit(shard_map(
+    sh_attn = jax.jit(jax.shard_map(
         partial(shard_ops.sharded_decode_attention, fmt=vp, mode="seq"),
         mesh=mesh,
         in_specs=(P(), P(None, "model"), P(None, "model"),
                   P(None, "model"), P(None, "model"), P()),
-        out_specs=P(), check_rep=False))
+        out_specs=P(), check_vma=False))
     o = np.asarray(sh_attn(q, k_w, v_w, ones, ones, lens))
     assert np.array_equal(o, o_ref), \
         f"seq-sharded attention lost bit parity at tp={tp} B={B} S={S}"
@@ -232,7 +237,7 @@ def main_worker(args) -> int:
         "n_devices": len(jax.devices()),
         "xla_flags": os.environ.get("XLA_FLAGS", ""),
         "tpu_async_flags": TPU_ASYNC_FLAGS,
-        "applied_async_flags": "LIBTPU_INIT_ARGS" in os.environ,
+        "applied_async_flags": jax.default_backend() == "tpu",
     }
     with open(args.out, "w", encoding="utf-8") as f:
         json.dump({"config": config, "rows": rows}, f, indent=1)

@@ -51,6 +51,7 @@ from typing import Any, Dict, Iterator, List, Optional, Sequence, Set, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.core import ClosedJaxpr
 
 # `*_ref` callables that are deliberately NOT jit-wrapped (mask builders
 # and helpers called at trace time inside an enclosing jit, where a
@@ -75,7 +76,7 @@ def _subjaxprs(eqn) -> Iterator[Tuple[Any, bool]]:
     for v in eqn.params.values():
         vals = v if isinstance(v, (tuple, list)) else (v,)
         for item in vals:
-            if isinstance(item, jax.core.ClosedJaxpr):
+            if isinstance(item, ClosedJaxpr):
                 yield item.jaxpr, is_pallas
             elif hasattr(item, "eqns") and hasattr(item, "outvars"):
                 yield item, is_pallas
@@ -84,7 +85,7 @@ def _subjaxprs(eqn) -> Iterator[Tuple[Any, bool]]:
 def iter_eqns(jaxpr, in_pallas: bool = False) -> Iterator[Tuple[Any, bool]]:
     """Depth-first walk over every eqn in a (closed) jaxpr, tagging
     whether the eqn sits inside a pallas_call kernel body."""
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:
         yield eqn, in_pallas
@@ -168,7 +169,7 @@ def lint_traced(
 def _shard_map_bodies(jaxpr) -> Iterator[Any]:
     """Yield the body jaxpr of every shard_map eqn, at any nesting depth
     outside of one (shard_map does not nest in this codebase)."""
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:
         if "shard_map" in eqn.primitive.name:

@@ -1,10 +1,10 @@
 """Per-kernel VMEM footprint model, checked against the TPU budget.
 
 Every Pallas kernel in `repro.kernels` stages block-spec tiles plus VMEM
-scratch on chip; a candidate tiling whose working set exceeds the ~16 MB
-per-core VMEM fails to lower (Mosaic "not enough VMEM"-class errors) —
-previously discovered only by TIMING the candidate inside
-`autotune.tune` and letting it lose.  This module computes the footprint
+scratch on chip; a candidate tiling whose working set exceeds the scoped
+VMEM a kernel may use (`vmem_budget_bytes`) fails to lower (Mosaic
+"not enough VMEM"-class errors) — previously discovered only by TIMING
+the candidate inside `autotune.tune` and letting it lose.  This module computes the footprint
 statically from the same quantities the launch uses (block shapes,
 operand dtypes, scratch shapes), so:
 
@@ -28,14 +28,23 @@ from __future__ import annotations
 import os
 from typing import Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.core.formats import FXPFormat, VPFormat
 from repro.core.packing import storage_dtype
 from repro.core.vp_tensor import significand_dtype
 
-# Per-core VMEM on contemporary TPUs (v4/v5 class): ~16 MiB.
-_DEFAULT_BUDGET = 16 * 1024 * 1024
+_MiB = 1024 * 1024
+# Scoped VMEM one kernel may use without raising `vmem_limit_bytes`, by
+# `device_kind`.  TPU v5e has 128 MiB of VMEM per core, of which the XLA
+# TPU compiler scopes 16 MiB to a kernel by default (JAX Pallas TPU
+# documentation; libtpu 0.0.34 refuses a 32 MiB kernel for a v5e with
+# "Scoped allocation with size 32.00M and limit 16.00M").
+_BUDGET_BY_KIND = {"TPU v5 lite": 16 * _MiB}
+# Off the chip (CPU tests, interpret mode, compile rehearsals for a
+# described v5e) the model keeps the v5e figure.
+_OFF_CHIP_BUDGET = _BUDGET_BY_KIND["TPU v5 lite"]
 _ENV_VAR = "REPRO_VMEM_BUDGET_BYTES"
 
 # Online-softmax scratch rows are lane-broadcast to the TPU lane count
@@ -45,9 +54,24 @@ _F32 = 4
 
 
 def vmem_budget_bytes() -> int:
-    """The VMEM budget (env override `REPRO_VMEM_BUDGET_BYTES`)."""
+    """The VMEM budget of JAX's first device.
+
+    `REPRO_VMEM_BUDGET_BYTES` overrides it.  A TPU whose `device_kind`
+    is not in the table raises: a guessed budget would prune tilings the
+    chip could run, or admit ones it refuses.
+    """
     env = os.environ.get(_ENV_VAR)
-    return int(env) if env else _DEFAULT_BUDGET
+    if env:
+        return int(env)
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return _OFF_CHIP_BUDGET
+    if dev.device_kind not in _BUDGET_BY_KIND:
+        raise ValueError(
+            f"no VMEM budget is known for {dev.device_kind!r}; add it to "
+            f"repro.analysis.vmem._BUDGET_BY_KIND with its source, or set "
+            f"{_ENV_VAR}")
+    return _BUDGET_BY_KIND[dev.device_kind]
 
 
 def _itemsize(dtype) -> int:

@@ -818,8 +818,8 @@ def vp_decode_attention(
         (B, Smax, KV, dh, window or 0, int(rolling)), (fmt,), backend,
         sq=G, sk=Smax, blocks=blocks)
     bs = blocks[1]
-    ks, vs = k_s.reshape(B, Smax), v_s.reshape(B, Smax)
-    kw, vw = k_w, v_w
+    ks, vs = k_s.reshape(B, Smax, 1), v_s.reshape(B, Smax, 1)
+    kw, vw = k_w.reshape(B, Smax, KV * dh), v_w.reshape(B, Smax, KV * dh)
     pad = (-Smax) % bs
     if pad:
         # The kernel masks padded positions (the real `Smax` rides the
@@ -834,10 +834,10 @@ def vp_decode_attention(
         if Smax % bs_div == 0:
             bs, pad = bs_div, 0
     if pad:
-        kw = jnp.pad(kw, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        vw = jnp.pad(vw, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        ks = jnp.pad(ks, ((0, 0), (0, pad)))
-        vs = jnp.pad(vs, ((0, 0), (0, pad)))
+        kw = jnp.pad(kw, ((0, 0), (0, pad), (0, 0)))
+        vw = jnp.pad(vw, ((0, 0), (0, pad), (0, 0)))
+        ks = jnp.pad(ks, ((0, 0), (0, pad), (0, 0)))
+        vs = jnp.pad(vs, ((0, 0), (0, pad), (0, 0)))
     qr = q.reshape(B, KV, G, dh).astype(jnp.float32) * dh ** -0.5
     gp = max(G, 8) if backend == "native" else G
     if gp != G:

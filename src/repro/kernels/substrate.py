@@ -1,13 +1,12 @@
-"""Shared Pallas kernel substrate: version compat, in-kernel helpers, dispatch.
+"""Shared Pallas kernel substrate: launch plumbing, in-kernel helpers, dispatch.
 
 Every VP kernel in this package launches through this module, so three
 concerns live in exactly one place instead of being cloned per kernel:
 
-  (a) jax/Pallas-TPU API compat — the compiler-params class was renamed
-      (`TPUCompilerParams` on jax 0.4.x, `CompilerParams` on newer jax) and
-      grid-spec construction differs between plain and scalar-prefetch
-      launches; `vp_pallas_call` absorbs both so kernels never import
-      `pallas.tpu` symbols directly.
+  (a) launch plumbing — grid-spec construction differs between plain and
+      scalar-prefetch launches; `vp_pallas_call` absorbs both (and the
+      TPU compiler params) so kernels never import `pallas.tpu` symbols
+      directly.
   (b) in-kernel VP math — the quantize cascade (paper Fig. 3), the
       dequant/scale-LUT select cascade (Fig. 5 barrel-mux analogue), and the
       k-loop accumulator init/flush idiom shared by every matmul kernel.
@@ -27,6 +26,7 @@ cloning it per format.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 from typing import Iterator, Optional, Sequence, Tuple
@@ -39,24 +39,8 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.formats import FXPFormat, VPFormat
 
 # ---------------------------------------------------------------------------
-# (a) jax-version compat shims
+# (a) launch plumbing
 # ---------------------------------------------------------------------------
-
-# jax >= 0.5 exposes `pltpu.CompilerParams`; 0.4.x calls it
-# `TPUCompilerParams`.  Same constructor signature for the fields we use.
-_COMPILER_PARAMS_CLS = (
-    getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-)
-
-
-def compiler_params(
-    dimension_semantics: Optional[Sequence[str]] = None, **kwargs
-):
-    """Build TPU compiler params across the CompilerParams rename."""
-    if dimension_semantics is not None:
-        kwargs["dimension_semantics"] = tuple(dimension_semantics)
-    return _COMPILER_PARAMS_CLS(**kwargs)
-
 
 def vmem(shape: Tuple[int, ...], dtype):
     """VMEM scratch allocation (kernels never touch pltpu directly)."""
@@ -80,12 +64,13 @@ def vp_pallas_call(
     With `num_scalar_prefetch > 0` the launch goes through
     `PrefetchScalarGridSpec` (index maps then receive the scalar refs as
     trailing args); otherwise through the plain grid/in_specs path.
-    `dimension_semantics` is attached via the version-robust compiler-params
-    shim; both forms accept VMEM scratch.
+    `dimension_semantics` rides the TPU compiler params; both forms
+    accept VMEM scratch.
     """
     kwargs = {}
     if dimension_semantics is not None:
-        kwargs["compiler_params"] = compiler_params(dimension_semantics)
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=tuple(dimension_semantics))
     if num_scalar_prefetch:
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=num_scalar_prefetch,
@@ -313,6 +298,23 @@ def accum_flush(o_ref, acc_ref, ki, nk: int):
         o_ref[...] = acc_ref[...].astype(o_ref.dtype).reshape(o_ref.shape)
 
 
+def cspade_launch(kernel, a_act, b_act, **statics):
+    """Bind a CSPADE-capable matmul kernel for one launch.
+
+    Masked launches scalar-prefetch the tile-activity flags flattened to
+    1-D — SMEM pads every trailing axis of a multi-dimensional operand,
+    so a (G, nm, nk) flag array for G = 1024 realizations would alone
+    overflow it.  Unmasked launches prefetch nothing; the kernel's two
+    flag refs are bound to None.  Returns (kernel, prefetch operands).
+    """
+    if a_act is None:
+        return functools.partial(kernel, None, None, cspade=False,
+                                 **statics), ()
+    masks = (a_act.reshape(-1).astype(jnp.int32),
+             b_act.reshape(-1).astype(jnp.int32))
+    return functools.partial(kernel, cspade=True, **statics), masks
+
+
 def batched_matmul_grid(
     nb: int, nm: int, nn: int, nk: int,
     bm: int, bk: int, bn: int,
@@ -357,6 +359,12 @@ def on_tpu() -> bool:
 # Set only by `force_backend`; overrides the interpret/platform mapping.
 _FORCED: list = []
 
+# Backends `resolve_backend` has handed out, by name.  Ops resolve while
+# they are traced, so this counts traced launches, not executions: a run
+# on the chip that shows any "ref" outside a deliberate `force_backend`
+# traced a jnp oracle where a kernel should have been.
+resolved: collections.Counter = collections.Counter()
+
 
 @contextlib.contextmanager
 def force_backend(backend: str) -> Iterator[None]:
@@ -396,7 +404,10 @@ def resolve_backend(interpret: Optional[bool]) -> str:
     tracing only).
     """
     if _FORCED:
-        return _FORCED[-1]
-    if interpret:
-        return "interpret"
-    return "native" if on_tpu() else "ref"
+        backend = _FORCED[-1]
+    elif interpret:
+        backend = "interpret"
+    else:
+        backend = "native" if on_tpu() else "ref"
+    resolved[backend] += 1
+    return backend

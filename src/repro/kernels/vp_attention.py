@@ -19,10 +19,11 @@ Two kernels, both on the shared substrate:
     (past `len`, before the sliding-window lower bound, or past the
     rolling ring's fill level) is SKIPPED via `pl.when` — the same
     static-bounds trick `flash_attention`'s pair enumeration uses, so
-    MXU work is O(cache_len · B · H · dh), not O(Smax).  Per-position
-    pow2 cache scales multiply the score/probability COLUMNS instead of
-    the K/V rows (exactly equal for power-of-two scales, and it keeps
-    every in-kernel operand in its natural layout).
+    MXU work is O(cache_len · B · H · dh), not O(Smax).  The cache
+    words arrive as (B, Smax, KV·dh) — a free reshape of the cache — so
+    each (bs, dh) head tile is a lane-aligned block; per-position pow2
+    cache scales arrive as (bs, 1) columns and scale the dequantized K/V
+    rows by a lane broadcast.
 
   * `flash_prefill_pallas` — q-chunk x k-chunk online-softmax attention
     (causal / local / full masks) for the prefill pass, replacing the
@@ -56,13 +57,11 @@ NEG_INF = -1e30
 _LANES = 128
 
 
-def _online_softmax_update(s, v, vs_row, m_ref, l_ref, acc_ref):
+def _online_softmax_update(s, v, m_ref, l_ref, acc_ref):
     """One flash-attention accumulation step for a scores tile `s`.
 
-    s (rows, bs) f32 scores (already masked), v (bs, dh) values,
-    `vs_row` (1, bs) per-position value scales folded into the
-    probability columns (p @ (v * vs) == (p * vs) @ v, exact for pow2
-    scales).  Updates the running (m, l, acc) scratch in place.
+    s (rows, bs) f32 scores (already masked), v (bs, dh) values.
+    Updates the running (m, l, acc) scratch in place.
     """
     m_prev = m_ref[...]                      # (rows, LANES), lanes equal
     l_prev = l_ref[...]
@@ -73,8 +72,6 @@ def _online_softmax_update(s, v, vs_row, m_ref, l_ref, acc_ref):
     l_next = alpha * l_prev + jnp.sum(p, axis=1)[:, None]
     m_ref[...] = m_next
     l_ref[...] = l_next
-    if vs_row is not None:
-        p = p * vs_row
     acc_ref[...] = acc_ref[...] * alpha[:, :1] + jax.lax.dot(
         p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
@@ -128,21 +125,16 @@ def _decode_attn_kernel(
     @pl.when(run)
     def _tile():
         q = q_ref[0, 0]                          # (Gp, dh) f32, pre-scaled
-        kw = kw_ref[0, :, 0, :]                  # (bs, dh) packed words
-        vw = vw_ref[0, :, 0, :]
-        ks_row = ks_ref[...].astype(jnp.float32)  # (1, bs) pow2 scales
-        vs_row = vs_ref[...].astype(jnp.float32)
-        k = sub.dequant_packed(kw, fmt, jnp.float32)
-        v = sub.dequant_packed(vw, fmt, jnp.float32)
-        # scores: q @ k^T, per-position cache scale folded into columns
+        # (bs, dh) packed words times (bs, 1) pow2 per-position scales.
+        k = sub.dequant_packed(kw_ref[0], fmt, jnp.float32) * ks_ref[0]
+        v = sub.dequant_packed(vw_ref[0], fmt, jnp.float32) * vs_ref[0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        s = s * ks_row
         pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         valid = (pos >= lo) & (pos < hi)
         s = jnp.where(valid, s, NEG_INF)
-        _online_softmax_update(s, v, vs_row, m_ref, l_ref, acc_ref)
+        _online_softmax_update(s, v, m_ref, l_ref, acc_ref)
 
     _flush(o_ref, m_ref, l_ref, acc_ref, ki, nk)
 
@@ -165,7 +157,8 @@ def vp_decode_attention_pallas(
     """Decode attention over a PACKED VP KV cache.
 
     q (B, KV, Gp, dh) f32, already scaled by dh**-0.5; k_w / v_w
-    (B, Smax_p, KV, dh) packed VP words; k_s / v_s (B, Smax_p)
+    (B, Smax_p, KV * dh) packed VP words (the cache's (B, Smax_p, KV, dh)
+    buffer with its head axes merged); k_s / v_s (B, Smax_p, 1)
     per-position pow2 cache scales; lengths (B,) int32 valid lengths.
     Smax_p must be a multiple of `bs` (ops.py pads).  `smax` is the REAL
     (pre-pad) buffer length: the rolling ring clamps its valid span to
@@ -180,9 +173,11 @@ def vp_decode_attention_pallas(
     kernel = functools.partial(
         _decode_attn_kernel, fmt=fmt, bs=bs, nk=nk, smax=smax,
         window=window, rolling=rolling)
-    cache_spec = pl.BlockSpec(
-        (1, bs, 1, dh), lambda b, h, ki, *_: (b, ki, h, 0))
-    scale_spec = pl.BlockSpec((1, bs), lambda b, h, ki, *_: (b, ki))
+    # Head h's words are columns [h*dh, (h+1)*dh) of the merged axis, so
+    # every block's trailing dims are (bs, dh) and (bs, 1): the shapes
+    # Mosaic tiles natively, with no copy of the cache.
+    cache_spec = pl.BlockSpec((1, bs, dh), lambda b, h, ki, *_: (b, ki, h))
+    scale_spec = pl.BlockSpec((1, bs, 1), lambda b, h, ki, *_: (b, ki, 0))
     return sub.vp_pallas_call(
         kernel,
         grid=(B, KV, nk),
@@ -251,7 +246,7 @@ def _flash_prefill_kernel(
             if pattern == "local" and window:
                 valid &= q_pos - k_pos < window
         s = jnp.where(valid, s, NEG_INF)
-        _online_softmax_update(s, v, None, m_ref, l_ref, acc_ref)
+        _online_softmax_update(s, v, m_ref, l_ref, acc_ref)
 
     _flush(o_ref, m_ref, l_ref, acc_ref, ki, nk)
 

@@ -10,8 +10,10 @@ TPU adaptation of the B-VP design:
     scale), plane tiles through `dequant_cascade` — and fed to the MXU in
     f32/bf16;
   * CSPADE is tile-granular: per-tile activity flags are scalar-prefetched
-    into SMEM and `pl.when` skips the MXU op when BOTH operand tiles are
-    quiet (the systolic-array analogue of partial-product muting).
+    into SMEM (flattened to 1-D, since SMEM pads every trailing axis) and
+    `pl.when` skips the MXU op when BOTH operand tiles are quiet (the
+    systolic-array analogue of partial-product muting).  Unmasked launches
+    prefetch nothing.
 
 Grid is (m, n, k) with k innermost; a VMEM f32 scratch accumulates across
 the k steps and is flushed to the output on the last step.  Launch plumbing
@@ -36,8 +38,8 @@ def _vp_matmul_kernel(
     a_act_ref, b_act_ref,
     # tensor operands (VMEM tiles): 2 plane refs per operand, or 1 packed
     *refs,
-    a_fmt: VPFormat, b_fmt: VPFormat, nk: int, cspade: bool, dtype,
-    packed: bool, batched: bool,
+    a_fmt: VPFormat, b_fmt: VPFormat, nm: int, nn: int, nk: int,
+    cspade: bool, dtype, packed: bool, batched: bool,
 ):
     o_ref, acc_ref = refs[-2], refs[-1]
     ki = pl.program_id(3 if batched else 2)
@@ -63,10 +65,10 @@ def _vp_matmul_kernel(
     if cspade:
         if batched:
             gi, mi, ni = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-            active = (a_act_ref[gi, mi, ki] | b_act_ref[gi, ki, ni]) != 0
         else:
-            mi, ni = pl.program_id(0), pl.program_id(1)
-            active = (a_act_ref[mi, ki] | b_act_ref[ki, ni]) != 0
+            gi, mi, ni = 0, pl.program_id(0), pl.program_id(1)
+        active = (a_act_ref[(gi * nm + mi) * nk + ki]
+                  | b_act_ref[(gi * nk + ki) * nn + ni]) != 0
         pl.when(active)(_compute)
     else:
         _compute()
@@ -108,14 +110,9 @@ def vp_matmul_batched_pallas(
     G, M, K = a_m.shape
     _, _, N = b_m.shape
     nm, nk, nn = M // bm, K // bk, N // bn
-    cspade = a_act is not None
-    if not cspade:
-        a_act = jnp.ones((G, nm, nk), jnp.int32)
-        b_act = jnp.ones((G, nk, nn), jnp.int32)
-
-    kernel = functools.partial(
-        _vp_matmul_kernel,
-        a_fmt=a_fmt, b_fmt=b_fmt, nk=nk, cspade=cspade, dtype=jnp.float32,
+    kernel, masks = sub.cspade_launch(
+        _vp_matmul_kernel, a_act, b_act,
+        a_fmt=a_fmt, b_fmt=b_fmt, nm=nm, nn=nn, nk=nk, dtype=jnp.float32,
         packed=packed, batched=True,
     )
     copies = 1 if packed else 2
@@ -129,10 +126,10 @@ def vp_matmul_batched_pallas(
         out_specs=out_specs,
         out_shape=jax.ShapeDtypeStruct((G, M, N), out_dtype),
         scratch_shapes=[sub.vmem((bm, bn), jnp.float32)],
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(masks),
         dimension_semantics=semantics,
         interpret=interpret,
-    )(a_act, b_act, *operands)
+    )(*masks, *operands)
 
 
 @functools.partial(
@@ -161,14 +158,9 @@ def vp_matmul_pallas(
     M, K = a_m.shape
     _, N = b_m.shape
     nm, nk, nn = M // bm, K // bk, N // bn
-    cspade = a_act is not None
-    if not cspade:
-        a_act = jnp.ones((nm, nk), jnp.int32)
-        b_act = jnp.ones((nk, nn), jnp.int32)
-
-    kernel = functools.partial(
-        _vp_matmul_kernel,
-        a_fmt=a_fmt, b_fmt=b_fmt, nk=nk, cspade=cspade, dtype=jnp.float32,
+    kernel, masks = sub.cspade_launch(
+        _vp_matmul_kernel, a_act, b_act,
+        a_fmt=a_fmt, b_fmt=b_fmt, nm=nm, nn=nn, nk=nk, dtype=jnp.float32,
         packed=packed, batched=False,
     )
     a_spec = pl.BlockSpec((bm, bk), lambda mi, ni, ki, *_: (mi, ki))
@@ -182,7 +174,7 @@ def vp_matmul_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki, *_: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[sub.vmem((bm, bn), jnp.float32)],
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(masks),
         dimension_semantics=("parallel", "parallel", "arbitrary"),
         interpret=interpret,
-    )(a_act, b_act, *operands)
+    )(*masks, *operands)

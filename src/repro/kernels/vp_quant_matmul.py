@@ -17,8 +17,8 @@ huge square matmuls.  Callers that reuse quantized operands across many
 products (or large grids) should prefer vp_quant + vp_matmul;
 mvm_engine gates its fused default on exactly this.
 
-CSPADE tile-activity masks work exactly as in `vp_matmul` (scalar-prefetch
-flags + `pl.when` skip).  Numerics are bit-identical to
+CSPADE tile-activity masks work exactly as in `vp_matmul` (flat
+scalar-prefetch flags + `pl.when` skip).  Numerics are bit-identical to
 `vp_quant` -> `vp_matmul`, which is what tests/test_substrate_kernels.py
 asserts against the ref oracles.
 """
@@ -44,7 +44,7 @@ def _vp_quant_matmul_kernel(
     # outputs / scratch
     o_ref, acc_ref,
     *, a_fxp: FXPFormat, a_vp: VPFormat, b_fxp: FXPFormat, b_vp: VPFormat,
-    nk: int, cspade: bool, dtype,
+    nm: int, nn: int, nk: int, cspade: bool, dtype,
 ):
     ki = pl.program_id(2)
     sub.accum_init(acc_ref, ki)
@@ -59,7 +59,7 @@ def _vp_quant_matmul_kernel(
 
     if cspade:
         mi, ni = pl.program_id(0), pl.program_id(1)
-        active = (a_act_ref[mi, ki] | b_act_ref[ki, ni]) != 0
+        active = (a_act_ref[mi * nk + ki] | b_act_ref[ki * nn + ni]) != 0
         pl.when(active)(_compute)
     else:
         _compute()
@@ -75,7 +75,7 @@ def _vp_quant_matmul_batched_kernel(
     # outputs / scratch
     o_ref, acc_ref,
     *, a_fxp: FXPFormat, a_vp: VPFormat, b_fxp: FXPFormat, b_vp: VPFormat,
-    nk: int, cspade: bool, dtype,
+    nm: int, nn: int, nk: int, cspade: bool, dtype,
 ):
     ki = pl.program_id(3)
     sub.accum_init(acc_ref, ki)
@@ -90,7 +90,8 @@ def _vp_quant_matmul_batched_kernel(
 
     if cspade:
         gi, mi, ni = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-        active = (a_act_ref[gi, mi, ki] | b_act_ref[gi, ki, ni]) != 0
+        active = (a_act_ref[(gi * nm + mi) * nk + ki]
+                  | b_act_ref[(gi * nk + ki) * nn + ni]) != 0
         pl.when(active)(_compute)
     else:
         _compute()
@@ -124,15 +125,10 @@ def vp_quant_matmul_batched_pallas(
     G, M, K = a.shape
     _, _, N = b.shape
     nm, nk, nn = M // bm, K // bk, N // bn
-    cspade = a_act is not None
-    if not cspade:
-        a_act = jnp.ones((G, nm, nk), jnp.int32)
-        b_act = jnp.ones((G, nk, nn), jnp.int32)
-
-    kernel = functools.partial(
-        _vp_quant_matmul_batched_kernel,
+    kernel, masks = sub.cspade_launch(
+        _vp_quant_matmul_batched_kernel, a_act, b_act,
         a_fxp=a_fxp, a_vp=a_vp, b_fxp=b_fxp, b_vp=b_vp,
-        nk=nk, cspade=cspade, dtype=jnp.float32,
+        nm=nm, nn=nn, nk=nk, dtype=jnp.float32,
     )
     grid, in_specs, out_specs, semantics = sub.batched_matmul_grid(
         G, nm, nn, nk, bm, bk, bn, a_copies=1, b_copies=1)
@@ -143,10 +139,10 @@ def vp_quant_matmul_batched_pallas(
         out_specs=out_specs,
         out_shape=jax.ShapeDtypeStruct((G, M, N), out_dtype),
         scratch_shapes=[sub.vmem((bm, bn), jnp.float32)],
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(masks),
         dimension_semantics=semantics,
         interpret=interpret,
-    )(a_act, b_act, a, b)
+    )(*masks, a, b)
 
 
 @functools.partial(
@@ -173,15 +169,10 @@ def vp_quant_matmul_pallas(
     M, K = a.shape
     _, N = b.shape
     nm, nk, nn = M // bm, K // bk, N // bn
-    cspade = a_act is not None
-    if not cspade:
-        a_act = jnp.ones((nm, nk), jnp.int32)
-        b_act = jnp.ones((nk, nn), jnp.int32)
-
-    kernel = functools.partial(
-        _vp_quant_matmul_kernel,
+    kernel, masks = sub.cspade_launch(
+        _vp_quant_matmul_kernel, a_act, b_act,
         a_fxp=a_fxp, a_vp=a_vp, b_fxp=b_fxp, b_vp=b_vp,
-        nk=nk, cspade=cspade, dtype=jnp.float32,
+        nm=nm, nn=nn, nk=nk, dtype=jnp.float32,
     )
     return sub.vp_pallas_call(
         kernel,
@@ -193,7 +184,7 @@ def vp_quant_matmul_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki, *_: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[sub.vmem((bm, bn), jnp.float32)],
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(masks),
         dimension_semantics=("parallel", "parallel", "arbitrary"),
         interpret=interpret,
-    )(a_act, b_act, a, b)
+    )(*masks, a, b)

@@ -15,11 +15,7 @@ from __future__ import annotations
 import math
 
 import jax
-
-try:  # jax >= 0.4.35
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes have no axis types
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mk(shape, axes):
@@ -32,12 +28,7 @@ def _mk(shape, axes):
             f"product is {n_have} (elastic_mesh / best_effort_mesh) or "
             f"launch with more devices "
             f"(--xla_force_host_platform_device_count on CPU)")
-    if AxisType is not None and hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    import numpy as np
-    from jax.sharding import Mesh
-    return Mesh(np.asarray(jax.devices()[:n_need]).reshape(shape), axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -29,6 +29,11 @@ paper's technique as a serving feature.
   --smoke           reduced config; also CHECKS finite logits end to end
                     (a real raise, not an assert — survives `python -O`)
 
+It runs on a TPU and raises if JAX finds none, unless the CPU was asked
+for with JAX_PLATFORMS=cpu (tests, rehearsals: kernels then run on their
+jnp refs).  Compiles persist in `launch.runtime.enable_compile_cache`'s
+directory.
+
 All wall-clock numbers come from `time.perf_counter()` — never
 `time.time()`, whose NTP steps skewed the committed tokens/sec reports —
 and token sampling happens INSIDE the jitted decode step, so "decode
@@ -45,6 +50,7 @@ import jax.numpy as jnp
 
 from repro.configs import registry
 from repro.configs.base import QuantConfig
+from repro.launch import runtime
 from repro.models import (
     init_params, init_cache, prefill, decode_step, quantize_params,
 )
@@ -302,6 +308,8 @@ def main():
                          "static golden-baseline path instead of "
                          "dropping them")
     args = ap.parse_args()
+    runtime.require_tpu()
+    runtime.enable_compile_cache()
 
     quant = QuantConfig(mode=args.quant, M=args.M, E=args.E,
                         block=args.block,

@@ -3,8 +3,11 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-0.6b --smoke \
         --steps 200 --batch 8 --seq 128 --ckpt-dir /tmp/ckpt [--quant vp]
 
-On CPU this trains the reduced (smoke) configs; on a TPU fleet the same
-driver runs the full configs under the production mesh (--mesh prod).
+It runs on a TPU and raises if JAX finds none, unless the CPU was asked
+for with JAX_PLATFORMS=cpu, where it trains the reduced (smoke) configs
+for tests and rehearsals; on a TPU fleet the same script runs the full
+configs under the production mesh (--mesh prod).  Compiles persist in
+`launch.runtime.enable_compile_cache`'s directory.
 The loop is crash-contained: every step the data position advances
 deterministically; on restart the latest INTACT checkpoint + data index
 resume bit-exactly (`CheckpointManager.restore_latest` walks past any
@@ -30,6 +33,7 @@ import jax.numpy as jnp
 
 from repro.configs import registry
 from repro.configs.base import QuantConfig
+from repro.launch import runtime
 from repro.models import init_params
 from repro.optim import OptConfig, init_opt_state
 from repro.optim.optimizer import OptState
@@ -83,6 +87,8 @@ def main():
                     help="simulated host id reporting 3x step durations")
     ap.add_argument("--ft-max-restarts", type=int, default=3)
     args = ap.parse_args()
+    runtime.require_tpu()
+    runtime.enable_compile_cache()
 
     quant = QuantConfig(mode=args.quant)
     cfg = (registry.get_smoke_config(args.arch, quant) if args.smoke

@@ -45,7 +45,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, QuantConfig
@@ -399,7 +399,7 @@ def sharded_forward_fns(params, cfg: ModelConfig, mesh, *,
         return shard_map(
             body, mesh=mesh,
             in_specs=(specs, arg_spec, cache_spec, P()),
-            out_specs=(out0, cache_spec), check_rep=False)
+            out_specs=(out0, cache_spec), check_vma=False)
 
     def prefill_fn(p, tokens, caches, patches=None, chunked=False):
         body = chunk_body if chunked else prefill_body

@@ -60,7 +60,6 @@ def shard_over_subcarriers(fn, mesh: Optional[Mesh] = None,
     degrades gracefully to running `fn` unsharded — callers never need a
     divisibility check on the serving path.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     if mesh is None:
@@ -69,8 +68,8 @@ def shard_over_subcarriers(fn, mesh: Optional[Mesh] = None,
     if n_dev == 1 or (n_subcarriers is not None and n_subcarriers % n_dev):
         return fn
     spec = PartitionSpec("sc")
-    return shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
-                     check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)
 
 
 def tp_size(mesh: Mesh) -> int:

@@ -14,6 +14,11 @@ import importlib.util
 import os
 import sys
 
+# The suite runs on the CPU: kernels in interpret mode or on their jnp
+# refs.  tests/test_tpu_compile.py compiles for a described TPU without
+# attaching one.  An explicit JAX_PLATFORMS wins.
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
 # Expose 8 host devices BEFORE anything imports jax, so the sharded
 # parity suite (tests/test_sharded_parity.py) runs in-process on real
 # shard_map meshes.  Harmless for the rest of the suite: ops dispatch

@@ -125,6 +125,7 @@ def test_full_configs_match_assignment():
     assert registry.get_config("mixtral-8x22b").sliding_window == 4096
     assert registry.get_config("gemma3-27b").local_global_period == 6
     assert registry.get_config("qwen3-0.6b").qk_norm
+    assert registry.get_config("qwen3-0.6b").head_dim == 128
     assert registry.get_config("qwen2-0.5b").qkv_bias
 
 

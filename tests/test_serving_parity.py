@@ -25,7 +25,7 @@ import pytest
 
 from repro.configs import registry
 from repro.configs.base import QuantConfig
-from repro.core.packing import pack_vp, unpack_vp
+from repro.core.packing import dequant_words, pack_vp, unpack_vp
 from repro.kernels import autotune, ops, substrate
 from repro.models import (
     init_params, init_cache, prefill, decode_step, quantize_params,
@@ -175,7 +175,12 @@ def test_model_logits_parity_vp(arch):
 def test_vp_dequant_matmul_kernel_interpret_parity(mkn):
     """The Pallas kernel body (interpreter) == the ref oracle == plain
     dequant-then-dot, including ragged shapes through the op's padding
-    (packed-word 0 decodes to real 0, so padding is exact)."""
+    (packed-word 0 decodes to real 0, so padding is exact).
+
+    The kernel accumulates k-tiles into an f32 scratch while the oracle
+    runs one dot, so the same K products are summed in another order:
+    each output may move by a few ulps of the magnitude its partial sums
+    reach, sum_k |x_k w_k| (3 at most on JAX 0.9.0).  Held to 4."""
     M, K, N = mkn
     q = QuantConfig(mode="vp")
     _, vp = canonical_formats(q)
@@ -186,8 +191,11 @@ def test_vp_dequant_matmul_kernel_interpret_parity(mkn):
     ref_out = ops.vp_dequant_matmul(x, wq["w_packed"], vp)
     kern_out = ops.vp_dequant_matmul(x, wq["w_packed"], vp, interpret=True)
     assert kern_out.shape == (M, N)
-    np.testing.assert_allclose(
-        np.asarray(kern_out), np.asarray(ref_out), rtol=1e-6, atol=1e-6)
+    w_real = dequant_words(wq["w_packed"], vp, jnp.float32)
+    magnitude = np.abs(np.asarray(x)) @ np.abs(np.asarray(w_real))
+    ulp = np.spacing(magnitude.astype(np.float32))
+    diff = np.abs(np.asarray(kern_out) - np.asarray(ref_out))
+    assert (diff <= 4 * ulp).all(), float((diff / ulp).max())
 
 
 # ---------------------------------------------------------------------------

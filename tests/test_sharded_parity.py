@@ -4,10 +4,12 @@ The contract under test: every collective on the sharded packed-VP
 datapath is a pure CONCATENATION (all-gather of output column blocks /
 head shards / expert outputs; the ppermute ring writes disjoint column
 blocks), so on the jnp ref backend the shard_map'd ops, the full-model
-forwards, and the mesh-constructed serving engine are all BIT-IDENTICAL
-to their single-device oracles — across the quant x KV-layout matrix,
-for all three weight-sharding modes, and for the expert-parallel MoE
-branch.  Runs on the 8-host-device platform `tests/conftest.py` pins.
+forwards, and the mesh-constructed serving engine match their
+single-device oracles — integer outputs bit for bit, float outputs to
+`F32_ULPS` ulps (see `assert_parity`) — across the quant x KV-layout
+matrix, for all three weight-sharding modes, and for the
+expert-parallel MoE branch.  Runs on the 8-host-device platform
+`tests/conftest.py` pins.
 
 Also here: the `shard_param_specs` placement rules (which leaves shard,
 which error when they cannot), the autotune mesh-key migration shim,
@@ -21,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, QuantConfig
@@ -37,6 +39,26 @@ from repro.parallel import shard_ops
 REF_BACKEND = substrate.resolve_backend(None) == "ref"
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs 8 host devices (conftest flag)")
+
+
+# A sharded matmul contracts each (M, K) x (K, N/tp) column block on its
+# own, while the oracle runs one (M, K) x (K, N) dot; XLA's CPU backend
+# may order the K-reduction differently for the two shapes, which moves
+# f32 results by a few ulps (at most 3 on JAX 0.9.0).  Float outputs are
+# held to F32_ULPS ulps of the array's largest magnitude; integer outputs
+# (packed words, lengths) are concatenations and must match exactly.
+F32_ULPS = 4
+
+
+def assert_parity(got, want):
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    if not np.issubdtype(want.dtype, np.floating):
+        assert np.array_equal(got, want)
+        return
+    scale = np.float32(np.abs(want).max()) if want.size else np.float32(0)
+    np.testing.assert_allclose(
+        got, want, rtol=0, atol=F32_ULPS * float(np.spacing(scale)))
 
 
 def _mesh(data=1, model=8):
@@ -77,8 +99,8 @@ def test_dequant_matmul_parity(mode, tp):
     fn = jax.jit(shard_map(
         partial(shard_ops.sharded_dequant_matmul, fmt=vp, mode=mode),
         mesh=_mesh(model=tp) if tp == 8 else _mesh(4, 2),
-        in_specs=(P(), P(None, "model")), out_specs=P(), check_rep=False))
-    assert np.array_equal(np.asarray(fn(x, w_pk)), y_ref)
+        in_specs=(P(), P(None, "model")), out_specs=P(), check_vma=False))
+    assert_parity(fn(x, w_pk), y_ref)
 
 
 def test_dequant_matmul_bad_mode():
@@ -113,7 +135,7 @@ def test_decode_attention_parity(mode):
         partial(shard_ops.sharded_decode_attention, fmt=vp, mode=mode),
         mesh=_mesh(model=8 if mode == "seq" else 4) if mode == "seq"
         else _mesh(2, 4),
-        in_specs=in_specs, out_specs=P(), check_rep=False))
+        in_specs=in_specs, out_specs=P(), check_vma=False))
     assert np.array_equal(np.asarray(fn(q, k_w, v_w, ones, ones, lens)),
                           o_ref)
 
@@ -132,7 +154,7 @@ def test_flash_prefill_parity():
         mesh=_mesh(2, 4),
         in_specs=(P(None, None, "model"), P(None, None, "model"),
                   P(None, None, "model")),
-        out_specs=P(), check_rep=False))
+        out_specs=P(), check_vma=False))
     assert np.array_equal(np.asarray(fn(q, k, v)), o_ref)
 
 
@@ -164,7 +186,7 @@ def test_sharded_matmul_dx_parity(mode, tp):
         partial(shard_ops.sharded_matmul_dx, fmt=vp, mode=mode),
         mesh=_mesh(model=tp) if tp == 8 else _mesh(4, 2),
         in_specs=(P(), P(None, "model")), out_specs=P(),
-        check_rep=False))
+        check_vma=False))
     np.testing.assert_allclose(np.asarray(fn(g, w_pk)), dx_ref,
                                rtol=1e-5, atol=1e-5)
 
@@ -179,7 +201,7 @@ def test_sharded_matmul_dx_ring_scatter_output():
         partial(shard_ops.sharded_matmul_dx, fmt=vp, mode="ring",
                 gather=False),
         mesh=_mesh(model=8), in_specs=(P(), P(None, "model")),
-        out_specs=P("model"), check_rep=False))
+        out_specs=P("model"), check_vma=False))
     np.testing.assert_allclose(np.asarray(fn(g, w_pk)), dx_ref,
                                rtol=1e-5, atol=1e-5)
 
@@ -201,7 +223,7 @@ def test_sharded_matmul_dw_local_bit_exact():
     fn = jax.jit(shard_map(
         partial(shard_ops.sharded_matmul_dw, fmt=vp),
         mesh=_mesh(model=8), in_specs=(P(), P()),
-        out_specs=P(None, "model"), check_rep=False))
+        out_specs=P(None, "model"), check_vma=False))
     assert np.array_equal(np.asarray(fn(x_pk, g)), dw_ref)
 
 
@@ -223,7 +245,7 @@ def test_dp_compress_reduce_oracle(codec):
         partial(shard_ops.dp_compress_reduce, axis="data", config=cfg),
         mesh=_mesh(8, 1),
         in_specs=({"w": P("data")}, {"w": P("data")}),
-        out_specs=({"w": P()}, {"w": P("data")}), check_rep=False))
+        out_specs=({"w": P()}, {"w": P("data")}), check_vma=False))
     red, new_state = fn(grads, state)
     deqs, errs = [], []
     for i in range(dp):
@@ -273,11 +295,11 @@ def _model_oracle_and_sharded(cfg, mesh, B=2, S=16, cap=32):
 def test_model_parity_dense(mode, kv):
     cfg = _tiny_cfg(_quant(mode, kv))
     (l1, d1, c1), (l2, d2, c2) = _model_oracle_and_sharded(cfg, _mesh())
-    assert np.array_equal(np.asarray(l1), np.asarray(l2))
-    assert np.array_equal(np.asarray(d1), np.asarray(d2))
+    assert_parity(l2, l1)
+    assert_parity(d2, d1)
     for a, b in zip(jax.tree_util.tree_leaves(c1),
                     jax.tree_util.tree_leaves(c2)):
-        assert np.array_equal(np.asarray(a), np.asarray(b))
+        assert_parity(b, a)
 
 
 @pytest.mark.skipif(not REF_BACKEND, reason="bit parity is a ref contract")
@@ -286,8 +308,8 @@ def test_model_parity_moe_expert_parallel(mode):
     cfg = _tiny_cfg(_quant(mode, "packed" if mode == "vp" else "float"),
                     family="moe")
     (l1, d1, _), (l2, d2, _) = _model_oracle_and_sharded(cfg, _mesh())
-    assert np.array_equal(np.asarray(l1), np.asarray(l2))
-    assert np.array_equal(np.asarray(d1), np.asarray(d2))
+    assert_parity(l2, l1)
+    assert_parity(d2, d1)
 
 
 # ---------------------------------------------------------------------------
@@ -392,7 +414,7 @@ def test_lint_flags_gather_not_ring():
         fn = shard_map(
             partial(shard_ops.sharded_dequant_matmul, fmt=vp, mode=mode),
             mesh=_mesh(), in_specs=(P(), P(None, "model")),
-            out_specs=P(), check_rep=False)
+            out_specs=P(), check_vma=False)
         return jax.make_jaxpr(fn)(x, w_pk)
 
     flagged = jaxpr_lint.lint_sharded_traced(traced("gather"), where="t")

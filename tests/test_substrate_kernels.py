@@ -41,8 +41,8 @@ def test_kernels_package_imports():
 
 
 def test_no_direct_compiler_params_outside_substrate():
-    """Version-drift guard: the renamed Pallas TPU symbols are referenced
-    only in substrate.py; every kernel launches through the shim."""
+    """Launch-plumbing guard: Pallas TPU symbols are referenced only in
+    substrate.py; every kernel launches through `vp_pallas_call`."""
     root = pathlib.Path(repro.kernels.__path__[0])
     for p in sorted(root.glob("*.py")):
         if p.name == "substrate.py":
