@@ -10,7 +10,7 @@ point's device count, so one driver binary sweeps mesh shapes that a
 single jax process could never revisit (device count is fixed at
 backend init).  Each point writes a config-stamped per-point JSON; the
 parent folds every row into one aggregate report (`--out`), the file
-committed as `BENCH_pr8.json` and appended to `BENCH_TRAJECTORY.json`.
+committed as `BENCH_pr8.json`.
 
 What each point measures, on a ("data", "model") best-effort mesh:
 
@@ -160,8 +160,7 @@ def main_worker(args) -> int:
     rows = []
 
     def emit(name, us, derived):
-        # dict rows, matching benchmarks/run.py — the trajectory ledger
-        # (benchmarks/trajectory.py) indexes rows by "name".
+        # dict rows, matching benchmarks/run.py
         rows.append({"name": name, "us_per_call": us, "derived": derived})
         print(f"{name},{us:.2f},{derived}")
 

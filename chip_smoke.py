@@ -279,42 +279,11 @@ def phase_reference(cfg, params, prompts):
           "greedy top-1 differs where the reference margin exceeds the bound")
 
 
-def _abstract(tree):
-    import jax
-
-    return jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding),
-        tree)
-
-
 def phase_kernels(engine):
     """Compile the engine's jitted prefill and decode steps for the
     argument shapes they ran with; count the Pallas kernels in each."""
-    import jax
-    import jax.numpy as jnp
-
-    runner, kv = engine.runner, engine.kv
-    params, pools, dense = _abstract((engine.params, kv.pools, kv.dense))
-    lengths, table = _abstract((kv.lengths, kv.block_table))
-    row = jax.ShapeDtypeStruct(table.shape[1:], table.dtype)
-    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
-    counts = {}
-    # The runner keeps one jitted step per prompt length and per decode
-    # bucket; these are the functions the engine dispatched above.
-    for S, fn in sorted(runner._prefill_fns.items()):
-        tokens = jax.ShapeDtypeStruct((1, S), jnp.int32)
-        compiled = fn.lower(params, tokens, pools, dense, row, lengths,
-                            jax.ShapeDtypeStruct((), jnp.int32),
-                            key).compile()
-        counts[f"prefill S={S}"] = compiled.as_text().count("tpu_custom_call")
-    for (Bp, n), fn in sorted(runner._decode_fns.items()):
-        tokens = jax.ShapeDtypeStruct((Bp, 1), jnp.int32)
-        compiled = fn.lower(params, tokens, pools, dense, table, lengths,
-                            jax.ShapeDtypeStruct((Bp,), jnp.int32),
-                            jax.ShapeDtypeStruct((Bp,), jnp.bool_),
-                            key).compile()
-        counts[f"decode Bp={Bp} steps={n}"] = \
-            compiled.as_text().count("tpu_custom_call")
+    counts = {name: text.count("tpu_custom_call")
+              for name, text in engine.runner.compiled_text().items()}
     for name, n in counts.items():
         log(f"kernels: engine {name}: {n} tpu_custom_call")
     check(any(k.startswith("prefill") for k in counts)

@@ -775,13 +775,15 @@ def vp_quant_matmul_batched(
             a, b, a_fxp, a_vp, b_fxp, b_vp,
             a_act=a_act, b_act=b_act, tiles=blocks, out_dtype=out_dtype)
     bm, bk, bn = blocks
-    ap, bp = _pad3(a, bm, bk), _pad3(b, bk, bn)
+    with jax.named_scope("pad"):
+        ap, bp = _pad3(a, bm, bk), _pad3(b, bk, bn)
     out = vp_quant_matmul_batched_pallas(
         ap, bp, a_fxp, a_vp, b_fxp, b_vp,
         a_act=a_act, b_act=b_act,
         interpret=(backend == "interpret"), blocks=blocks,
         out_dtype=out_dtype)
-    return out[:, :M, :N]
+    with jax.named_scope("pad"):
+        return out[:, :M, :N]
 
 
 def vp_decode_attention(

@@ -246,14 +246,19 @@ def equalize_wideband(
                 "wideband batch requires one static format across the band "
                 "(only AGC gains may vary per subcarrier)")
 
-    a, b, g = _stack_operands(specs, w, y)
+    # Named scopes `operands` and `combine` label the compiled step's
+    # operations around the kernel (metadata only).
+    with jax.named_scope("operands"):
+        a, b, g = _stack_operands(specs, w, y)
 
     def _flat(a_f, b_f):
         S_f = a_f.shape[0]
+        with jax.named_scope("operands"):
+            a_g = a_f.reshape(S_f * n, 2 * U, B)
+            b_g = b_f.reshape(S_f * n, B, 2)
         out = batched_complex_mvm(
-            a_f.reshape(S_f * n, 2 * U, B), b_f.reshape(S_f * n, B, 2),
-            fxp_w, vp_w, fxp_y, vp_y, interpret=interpret, fused=fused,
-            blocks=blocks)
+            a_g, b_g, fxp_w, vp_w, fxp_y, vp_y, interpret=interpret,
+            fused=fused, blocks=blocks)
         return out.reshape(S_f, n, 2 * U, 2)
 
     if how == "flat":
@@ -270,7 +275,8 @@ def equalize_wideband(
         raise ValueError(
             f"unknown how {how!r} (want 'flat', 'vmap' or 'shard_map')")
 
-    return combine_products(out, g)
+    with jax.named_scope("combine"):
+        return combine_products(out, g)
 
 
 def wideband_nmse(s_hat, s_true) -> float:

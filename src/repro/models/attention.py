@@ -431,13 +431,14 @@ def attn_block(
             # decode-attention kernel op — unpack + bit-assembled pow2
             # scale happen in-tile, and seq tiles outside the valid span
             # are skipped.  The whole cache is never dequantized in XLA.
-            w_k, s_k = quantize_kv(kp, q_cfg)
-            w_v, s_v = quantize_kv(vp_, q_cfg)
-            new_cache = dict(
-                k_w=upd(cache["k_w"], w_k), k_s=upd(cache["k_s"], s_k),
-                v_w=upd(cache["v_w"], w_v), v_s=upd(cache["v_s"], s_v),
-                len=idx + kp.shape[1],
-            )
+            with jax.named_scope("kv_append"):
+                w_k, s_k = quantize_kv(kp, q_cfg)
+                w_v, s_v = quantize_kv(vp_, q_cfg)
+                new_cache = dict(
+                    k_w=upd(cache["k_w"], w_k), k_s=upd(cache["k_s"], s_k),
+                    v_w=upd(cache["v_w"], w_v), v_s=upd(cache["v_s"], s_v),
+                    len=idx + kp.shape[1],
+                )
             _, vp_fmt = kv_cache_formats(q_cfg)
             out = kops.vp_decode_attention(
                 qp, new_cache["k_w"], new_cache["v_w"],
@@ -445,15 +446,18 @@ def attn_block(
                 vp_fmt, window=window, rolling=rolling)
         else:
             if "k_m" in cache:  # legacy planes VP cache (golden baseline)
-                m_k, i_k, s_k = quantize_kv(kp, q_cfg, layout="planes")
-                m_v, i_v, s_v = quantize_kv(vp_, q_cfg, layout="planes")
-                new_cache = dict(
-                    k_m=upd(cache["k_m"], m_k), k_i=upd(cache["k_i"], i_k),
-                    k_s=upd(cache["k_s"], s_k),
-                    v_m=upd(cache["v_m"], m_v), v_i=upd(cache["v_i"], i_v),
-                    v_s=upd(cache["v_s"], s_v),
-                    len=idx + kp.shape[1],
-                )
+                with jax.named_scope("kv_append"):
+                    m_k, i_k, s_k = quantize_kv(kp, q_cfg, layout="planes")
+                    m_v, i_v, s_v = quantize_kv(vp_, q_cfg, layout="planes")
+                    new_cache = dict(
+                        k_m=upd(cache["k_m"], m_k),
+                        k_i=upd(cache["k_i"], i_k),
+                        k_s=upd(cache["k_s"], s_k),
+                        v_m=upd(cache["v_m"], m_v),
+                        v_i=upd(cache["v_i"], i_v),
+                        v_s=upd(cache["v_s"], s_v),
+                        len=idx + kp.shape[1],
+                    )
                 k_full = dequantize_kv(
                     new_cache["k_m"], new_cache["k_i"], new_cache["k_s"],
                     q_cfg, kp.dtype)
@@ -461,10 +465,11 @@ def attn_block(
                     new_cache["v_m"], new_cache["v_i"], new_cache["v_s"],
                     q_cfg, vp_.dtype)
             else:
-                new_cache = dict(
-                    k=upd(cache["k"], kp), v=upd(cache["v"], vp_),
-                    len=idx + kp.shape[1],
-                )
+                with jax.named_scope("kv_append"):
+                    new_cache = dict(
+                        k=upd(cache["k"], kp), v=upd(cache["v"], vp_),
+                        len=idx + kp.shape[1],
+                    )
                 k_full, v_full = new_cache["k"], new_cache["v"]
             out = decode_attention(
                 qp, k_full, v_full, new_cache["len"], window,

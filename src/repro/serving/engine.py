@@ -13,9 +13,12 @@ One engine iteration interleaves BOTH kinds of work:
 
 so new requests reach their first token without draining the running
 batch, and running requests never stall behind a long prompt for more
-than one prefill unit.  All numbers the engine reports come from the
-injected clock (`perf_counter`-backed wall clock by default, virtual
-clock for deterministic benchmarks) — never `time.time()`.
+than one prefill unit.  Each iteration is a `repro.tracing` span
+(`engine.step`, with `engine.admit`, `engine.prefill`, `engine.decode`
+and `engine.retire` inside), recorded only while tracing is on.  All
+numbers the engine reports come from the injected clock
+(`perf_counter`-backed wall clock by default, virtual clock for
+deterministic benchmarks) — never `time.time()`.
 
 Budgets: `hbm_budget_bytes` sizes the page pool (admission is then a
 free-list question), and at construction the engine consults the PR-6
@@ -54,7 +57,6 @@ fault class against these contracts.
 """
 from __future__ import annotations
 
-import collections
 import time
 from typing import Dict, List, Optional, Sequence
 
@@ -62,6 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.analysis.vmem import vmem_feasible
 from repro.configs.base import ModelConfig
 from repro.models.attention import kv_cache_formats
@@ -133,6 +136,8 @@ class ServingEngine:
         self.decode_lookahead = int(decode_lookahead)
         self.runner = ModelRunner(cfg, self.kv, temperature=temperature,
                                   mesh=mesh)
+        # one set of counters for the engine and its runner's compiles
+        self.stats = self.runner.stats
         self.scheduler = Scheduler(self.kv, policy=policy, preempt=preempt)
         self.clock = clock if clock is not None else WallClock()
         self.check_finite = bool(check_finite)
@@ -144,7 +149,6 @@ class ServingEngine:
         self.degrade_after = int(degrade_after)
         self.degrade_params = degrade_params
         self.faults = faults
-        self.stats = collections.Counter()
         self._quarantine_counts: Dict[int, int] = {}
         self._decode_fail_streak = 0
         self._key = jax.random.PRNGKey(seed)
@@ -453,22 +457,28 @@ class ServingEngine:
 
     def _retire(self) -> None:
         now = self.clock.now()
-        for run in [r for r in self.scheduler.running.values() if r.done]:
-            self.scheduler.finish(run, now)
-            run.outcome = "retried" if run.retries > 0 else "ok"
-            self.stats[run.outcome] += 1
-            self.finished.append(run)
+        done = [r for r in self.scheduler.running.values() if r.done]
+        with tracing.span("engine.retire") as attrs:
+            attrs["rids"] = [r.req.rid for r in done]
+            for run in done:
+                self.scheduler.finish(run, now)
+                run.outcome = "retried" if run.retries > 0 else "ok"
+                self.stats[run.outcome] += 1
+                self.finished.append(run)
 
     # -- main loop ----------------------------------------------------------
 
-    def step(self) -> bool:
-        """One engine iteration; returns False when fully idle."""
+    def _iterate(self) -> bool:
+        """Admission, one prefill unit, one decode step, retirement;
+        returns whether any compute ran."""
         sched = self.scheduler
         if self.faults is not None:
             self.faults.on_step(self)
         now = self.clock.now()
         self._record_timeouts(sched.expire(now), now)
-        sched.admit(now)
+        with tracing.span("engine.admit",
+                          waiting=len(sched.waiting)) as attrs:
+            attrs["admitted"] = len(sched.admit(now))
         if sched.preempted_log:
             self.stats["preemptions"] += len(sched.preempted_log)
             sched.preempted_log.clear()
@@ -476,13 +486,29 @@ class ServingEngine:
         did = False
         run = sched.next_prefill()
         if run is not None:
-            self._prefill_unit(run)
+            with tracing.span("engine.prefill", rid=run.req.rid,
+                              tokens=len(run.prefill_source)):
+                self._prefill_unit(run)
             did = True
         decoding = sched.decoding()
         if decoding:
-            self._decode_once(decoding)
+            with tracing.span("engine.decode",
+                              rows=len(decoding)) as attrs:
+                if tracing.active():
+                    attrs["rids"] = [r.req.rid for r in decoding]
+                    attrs["live"] = [len(r.prefill_source) + len(r.tokens)
+                                     for r in decoding]
+                self._decode_once(decoding)
             did = True
         self._retire()
+        return did
+
+    def step(self) -> bool:
+        """One engine iteration (span `engine.step`); returns False when
+        fully idle."""
+        sched = self.scheduler
+        with tracing.span("engine.step"):
+            did = self._iterate()
         if did:
             return True
         if sched.idle:

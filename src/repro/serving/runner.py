@@ -27,6 +27,13 @@ Batch steps run at power-of-two slot buckets (compile per bucket, not
 per composition); inactive padding rows are distinct parked slots whose
 commits are masked to the dummy page / their own old rows.
 
+Each program is named for its shapes (`decode_b{Bp}_s{steps}`,
+`prefill_s{S}`, `chunk_c{C}`; the compiled module is `jit_<name>`) and
+counted in `compiles.<name>` when built; the stages above are named
+scopes (`gather`, `model`, `sample`, `commit`), which label the compiled
+operations and change nothing else.  Each call is a `repro.tracing`
+span with `dispatch`, `wait` and `fetch` children.
+
 Mesh-native serving: constructed with a `mesh`, the runner swaps the
 model calls for `parallel.shard_ops.sharded_forward_fns` — the SAME
 compute inside `shard_map`, weights tensor-parallel over the "model"
@@ -41,12 +48,14 @@ ref backend.
 """
 from __future__ import annotations
 
+import collections
 from typing import Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.configs.base import ModelConfig
 from repro.kernels import paged
 from repro.models import decode_step, prefill
@@ -60,6 +69,13 @@ def _sample(logits, key, temperature: float):
     else:
         tok = jnp.argmax(logits, -1)
     return tok.astype(jnp.int32)[:, None]
+
+
+def _abstract(x):
+    """An array's shape, dtype and sharding; anything else as it is."""
+    if not isinstance(x, jax.Array):
+        return x
+    return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
 
 
 def build_view(specs: Sequence[SubSpec], n_groups: int, pools, dense,
@@ -169,11 +185,27 @@ class ModelRunner:
         # the composition only changes on admission/retirement, so this
         # avoids two host->device transfers on every decode step.
         self._comp_cache: Dict[Tuple[Tuple[int, ...], int], tuple] = {}
+        # `compiles.<program>`: one per program built
+        self.stats = collections.Counter()
+        # program name -> (jitted fn, abstract arguments of its first call)
+        self._programs: Dict[str, tuple] = {}
 
     # -- compiled-step builders --------------------------------------------
 
-    def _jit(self, fn, donate):
+    def _jit(self, fn, name: str, donate):
+        """`fn` jitted as the program `name` (the compiled module is
+        `jit_<name>`); counted in `compiles.<name>`."""
+        fn.__name__ = fn.__qualname__ = name
+        self.stats[f"compiles.{name}"] += 1
+        tracing.count(f"compiles.{name}")
         return jax.jit(fn, donate_argnums=donate if self._donate else ())
+
+    def compiled_text(self) -> Dict[str, str]:
+        """The optimized HLO text of every program the runner has run, by
+        program name.  Each is compiled again from its first call's
+        shapes (a hit in the compile cache where one is set)."""
+        return {name: fn.lower(*args).compile().as_text()
+                for name, (fn, args) in sorted(self._programs.items())}
 
     def _model_fns(self, params):
         """(prefill_fn, decode_fn) — the plain model functions, or their
@@ -234,19 +266,21 @@ class ModelRunner:
             logits, filled = prefill_fn(
                 params, tokens, self._fresh_cache(Sp))
             nxt = _sample(logits, key, temperature)
-            for spec in kv.specs:
-                entry = filled[spec.gi][spec.sub]
-                for name, _, _ in spec.bufs:
-                    k = buf_key(spec, name)
-                    if spec.kind == PAGED:
-                        pools[k] = paged.scatter_pages(
-                            pools[k], bt_row[:n_pg], entry[name][:, 0])
-                    else:
-                        dense[k] = dense[k].at[:, slot].set(entry[name][:, 0])
-            lengths = lengths.at[slot].set(S)
+            with jax.named_scope("commit"):
+                for spec in kv.specs:
+                    entry = filled[spec.gi][spec.sub]
+                    for name, _, _ in spec.bufs:
+                        k = buf_key(spec, name)
+                        if spec.kind == PAGED:
+                            pools[k] = paged.scatter_pages(
+                                pools[k], bt_row[:n_pg], entry[name][:, 0])
+                        else:
+                            dense[k] = dense[k].at[:, slot].set(
+                                entry[name][:, 0])
+                lengths = lengths.at[slot].set(S)
             return nxt, logits, pools, dense, lengths
 
-        return self._jit(fn, donate=(2, 3, 5))
+        return self._jit(fn, f"prefill_s{S}", donate=(2, 3, 5))
 
     def _make_chunk(self, C: int):
         kv = self.kv
@@ -262,25 +296,27 @@ class ModelRunner:
             logits, new_caches = prefill_fn(params, tokens, view,
                                             chunked=True)
             nxt = _sample(logits, key, temperature)
-            pos0 = lengths[slot]
-            idxs = pos0 + jnp.arange(C, dtype=jnp.int32)
-            for spec in kv.specs:
-                entry = new_caches[spec.gi][spec.sub]
-                for name, tail, _ in spec.bufs:
-                    k = buf_key(spec, name)
-                    if spec.kind == PAGED:
-                        idx = idxs.reshape((1, 1, C) + (1,) * len(tail))
-                        val = jnp.take_along_axis(
-                            entry[name], idx, axis=2)[:, 0]
-                        pools[k] = paged.scatter_positions(
-                            pools[k], block_table[slot][idxs // ps],
-                            idxs % ps, val)
-                    else:
-                        dense[k] = dense[k].at[:, slot].set(entry[name][:, 0])
-            lengths = lengths.at[slot].set(pos0 + C)
+            with jax.named_scope("commit"):
+                pos0 = lengths[slot]
+                idxs = pos0 + jnp.arange(C, dtype=jnp.int32)
+                for spec in kv.specs:
+                    entry = new_caches[spec.gi][spec.sub]
+                    for name, tail, _ in spec.bufs:
+                        k = buf_key(spec, name)
+                        if spec.kind == PAGED:
+                            idx = idxs.reshape((1, 1, C) + (1,) * len(tail))
+                            val = jnp.take_along_axis(
+                                entry[name], idx, axis=2)[:, 0]
+                            pools[k] = paged.scatter_positions(
+                                pools[k], block_table[slot][idxs // ps],
+                                idxs % ps, val)
+                        else:
+                            dense[k] = dense[k].at[:, slot].set(
+                                entry[name][:, 0])
+                lengths = lengths.at[slot].set(pos0 + C)
             return nxt, logits, pools, dense, lengths
 
-        return self._jit(fn, donate=(2, 3, 5))
+        return self._jit(fn, f"chunk_c{C}", donate=(2, 3, 5))
 
     def _make_decode(self, Bp: int, n_steps: int):
         """Fused decode: gather the slot views ONCE, run `n_steps`
@@ -301,85 +337,123 @@ class ModelRunner:
 
         def fn(params, tokens, pools, dense, block_table, lengths, slots,
                active, key):
-            view = build_view(kv.specs, kv.group_count, pools, dense,
-                              block_table, lengths, slots)
+            with jax.named_scope("gather"):
+                view = build_view(kv.specs, kv.group_count, pools, dense,
+                                  block_table, lengths, slots)
             _, decode_fn = self._model_fns(params)
 
             def body(carry, i):
                 toks, caches = carry
                 logits, caches = decode_fn(params, toks, caches,
                                            batch_sharded=batch_sharded)
-                nxt = _sample(logits, jax.random.fold_in(key, i),
-                              temperature)
+                with jax.named_scope("sample"):
+                    nxt = _sample(logits, jax.random.fold_in(key, i),
+                                  temperature)
                 return (nxt, caches), (nxt, logits)
 
-            (_, view), (nxts, logits) = jax.lax.scan(
-                body, (tokens, view),
-                jnp.arange(n_steps, dtype=jnp.int32))
-            pos0 = jnp.where(active, lengths[slots], 0)
-            idxs = pos0[:, None] + jnp.arange(
-                n_steps, dtype=jnp.int32)[None]
-            for spec in kv.specs:
-                entry = view[spec.gi][spec.sub]
-                if spec.kind == PAGED:
-                    # Inactive rows scatter to the dummy page 0; nothing
-                    # reads it, so collisions there are harmless.
-                    pages = jnp.where(
-                        active[:, None],
-                        jnp.take_along_axis(block_table[slots],
-                                            idxs // ps, axis=1), 0)
-                    for name, tail, _ in spec.bufs:
-                        k = buf_key(spec, name)
-                        idx = idxs.reshape(
-                            (1, Bp, n_steps) + (1,) * len(tail))
-                        val = jnp.take_along_axis(entry[name], idx, axis=2)
-                        pools[k] = paged.scatter_positions(
-                            pools[k], pages, idxs % ps, val)
-                else:
-                    for name, _, _ in spec.bufs:
-                        k = buf_key(spec, name)
-                        nb = entry[name]
-                        mask = active.reshape(
-                            (1, Bp) + (1,) * (nb.ndim - 2))
-                        dense[k] = dense[k].at[:, slots].set(
-                            jnp.where(mask, nb, dense[k][:, slots]))
-            lengths = lengths.at[slots].add(
-                n_steps * active.astype(jnp.int32))
-            nxts = jnp.where(active[None, :, None], nxts, 0)
+            with jax.named_scope("model"):
+                (_, view), (nxts, logits) = jax.lax.scan(
+                    body, (tokens, view),
+                    jnp.arange(n_steps, dtype=jnp.int32))
+            with jax.named_scope("commit"):
+                pos0 = jnp.where(active, lengths[slots], 0)
+                idxs = pos0[:, None] + jnp.arange(
+                    n_steps, dtype=jnp.int32)[None]
+                for spec in kv.specs:
+                    entry = view[spec.gi][spec.sub]
+                    if spec.kind == PAGED:
+                        # Inactive rows scatter to the dummy page 0;
+                        # nothing reads it, so collisions there are
+                        # harmless.
+                        pages = jnp.where(
+                            active[:, None],
+                            jnp.take_along_axis(block_table[slots],
+                                                idxs // ps, axis=1), 0)
+                        for name, tail, _ in spec.bufs:
+                            k = buf_key(spec, name)
+                            idx = idxs.reshape(
+                                (1, Bp, n_steps) + (1,) * len(tail))
+                            val = jnp.take_along_axis(entry[name], idx,
+                                                      axis=2)
+                            pools[k] = paged.scatter_positions(
+                                pools[k], pages, idxs % ps, val)
+                    else:
+                        for name, _, _ in spec.bufs:
+                            k = buf_key(spec, name)
+                            nb = entry[name]
+                            mask = active.reshape(
+                                (1, Bp) + (1,) * (nb.ndim - 2))
+                            dense[k] = dense[k].at[:, slots].set(
+                                jnp.where(mask, nb, dense[k][:, slots]))
+                lengths = lengths.at[slots].add(
+                    n_steps * active.astype(jnp.int32))
+            with jax.named_scope("sample"):
+                nxts = jnp.where(active[None, :, None], nxts, 0)
             return nxts, logits, pools, dense, lengths
 
-        return self._jit(fn, donate=(2, 3, 5))
+        return self._jit(fn, f"decode_b{Bp}_s{n_steps}", donate=(2, 3, 5))
 
     # -- public steps (thread kv state functionally) ------------------------
+    #
+    # Each step is a span `runner.<step>` (its program's first call has
+    # `first=True`) with three children: `dispatch`, from entry until
+    # the jitted call returns; `wait`, for the outputs, only while the
+    # recorder is on; `fetch`, the results to the host.
+
+    def _call(self, fn, args):
+        """Call a program; the shapes of its first call are kept, so that
+        `compiled_text` can compile it again after the fact."""
+        if fn.__name__ not in self._programs:
+            self._programs[fn.__name__] = (
+                fn, jax.tree_util.tree_map(_abstract, args))
+        return fn(*args)
+
+    @staticmethod
+    def _wait(span: str, out) -> None:
+        if tracing.active():
+            with tracing.span(span + ".wait"):
+                jax.block_until_ready(out)
 
     def prefill_commit(self, params, prompt, slot: int, key):
         """Whole-prompt prefill into the slot's pages; returns
-        (first sampled token (1,1), last-position logits (1, V))."""
+        (first sampled token (1,1) on the host, last-position logits
+        (1, V))."""
         kv = self.kv
         S = int(prompt.shape[-1])
         fn = self._prefill_fns.get(S)
-        if fn is None:
-            fn = self._prefill_fns[S] = self._make_prefill(S)
-        tokens = jnp.asarray(prompt, jnp.int32).reshape(1, S)
-        bt_row = kv.block_table[slot]
-        nxt, logits, kv.pools, kv.dense, kv.lengths = fn(
-            params, tokens, kv.pools, kv.dense, bt_row, kv.lengths,
-            jnp.int32(slot), key)
+        with tracing.span("runner.prefill", tokens=S, first=fn is None):
+            with tracing.span("runner.prefill.dispatch"):
+                if fn is None:
+                    fn = self._prefill_fns[S] = self._make_prefill(S)
+                tokens = jnp.asarray(prompt, jnp.int32).reshape(1, S)
+                bt_row = kv.block_table[slot]
+                nxt, logits, kv.pools, kv.dense, kv.lengths = self._call(
+                    fn, (params, tokens, kv.pools, kv.dense, bt_row,
+                         kv.lengths, jnp.int32(slot), key))
+            self._wait("runner.prefill", (nxt, logits))
+            with tracing.span("runner.prefill.fetch"):
+                nxt = np.asarray(nxt)
         return nxt, logits
 
     def chunk_prefill_commit(self, params, chunk, slot: int, key):
         """One prompt chunk through the offset-aware prefill path;
-        returns (sampled token, logits) — only the FINAL chunk's sample
-        is the request's first generated token."""
+        returns (sampled token on the host, logits) — only the FINAL
+        chunk's sample is the request's first generated token."""
         kv = self.kv
         C = int(chunk.shape[-1])
         fn = self._chunk_fns.get(C)
-        if fn is None:
-            fn = self._chunk_fns[C] = self._make_chunk(C)
-        tokens = jnp.asarray(chunk, jnp.int32).reshape(1, C)
-        nxt, logits, kv.pools, kv.dense, kv.lengths = fn(
-            params, tokens, kv.pools, kv.dense, kv.block_table, kv.lengths,
-            jnp.int32(slot), key)
+        with tracing.span("runner.prefill", tokens=C, chunked=True,
+                          first=fn is None):
+            with tracing.span("runner.prefill.dispatch"):
+                if fn is None:
+                    fn = self._chunk_fns[C] = self._make_chunk(C)
+                tokens = jnp.asarray(chunk, jnp.int32).reshape(1, C)
+                nxt, logits, kv.pools, kv.dense, kv.lengths = self._call(
+                    fn, (params, tokens, kv.pools, kv.dense,
+                         kv.block_table, kv.lengths, jnp.int32(slot), key))
+            self._wait("runner.prefill", (nxt, logits))
+            with tracing.span("runner.prefill.fetch"):
+                nxt = np.asarray(nxt)
         return nxt, logits
 
     def decode_batch(self, params, slot_tokens: Dict[int, int], key,
@@ -403,24 +477,32 @@ class ModelRunner:
         while Bp < len(act):
             Bp <<= 1
         Bp = min(Bp, kv.max_slots) if Bp > len(act) else Bp
-        pad = [s for s in range(kv.max_slots) if s not in slot_tokens]
-        slots = act + pad[:Bp - len(act)]
-        comp = self._comp_cache.get((tuple(slots), len(act)))
-        if comp is None:
-            comp = (jnp.asarray(slots, jnp.int32),
-                    jnp.asarray([True] * len(act)
-                                + [False] * (Bp - len(act)), bool))
-            self._comp_cache[(tuple(slots), len(act))] = comp
-        tokens = [slot_tokens.get(s, 0) for s in slots]
         fn = self._decode_fns.get((Bp, steps))
-        if fn is None:
-            fn = self._decode_fns[(Bp, steps)] = self._make_decode(
-                Bp, steps)
-        nxt, logits, kv.pools, kv.dense, kv.lengths = fn(
-            params, jnp.asarray(np.asarray(tokens, np.int32)[:, None]),
-            kv.pools, kv.dense, kv.block_table, kv.lengths,
-            comp[0], comp[1], key)
-        nxt_h = np.asarray(nxt)          # (steps, Bp, 1)
-        logits_h = np.asarray(logits)    # (steps, Bp, V)
-        return {s: ([int(t) for t in nxt_h[:, i, 0]], logits_h[:, i])
-                for i, s in enumerate(act)}
+        with tracing.span("runner.decode", rows=len(act), Bp=Bp,
+                          steps=steps, first=fn is None):
+            with tracing.span("runner.decode.dispatch"):
+                pad = [s for s in range(kv.max_slots)
+                       if s not in slot_tokens]
+                slots = act + pad[:Bp - len(act)]
+                comp = self._comp_cache.get((tuple(slots), len(act)))
+                if comp is None:
+                    comp = (jnp.asarray(slots, jnp.int32),
+                            jnp.asarray([True] * len(act)
+                                        + [False] * (Bp - len(act)), bool))
+                    self._comp_cache[(tuple(slots), len(act))] = comp
+                tokens = [slot_tokens.get(s, 0) for s in slots]
+                if fn is None:
+                    fn = self._decode_fns[(Bp, steps)] = self._make_decode(
+                        Bp, steps)
+                nxt, logits, kv.pools, kv.dense, kv.lengths = self._call(
+                    fn, (params,
+                         jnp.asarray(np.asarray(tokens, np.int32)[:, None]),
+                         kv.pools, kv.dense, kv.block_table, kv.lengths,
+                         comp[0], comp[1], key))
+            self._wait("runner.decode", (nxt, logits))
+            with tracing.span("runner.decode.fetch"):
+                nxt_h = np.asarray(nxt)          # (steps, Bp, 1)
+                logits_h = np.asarray(logits)    # (steps, Bp, V)
+                return {s: ([int(t) for t in nxt_h[:, i, 0]],
+                            logits_h[:, i])
+                        for i, s in enumerate(act)}
