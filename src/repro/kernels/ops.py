@@ -51,7 +51,11 @@ from repro.analysis import contracts
 from repro.core.formats import FXPFormat, VPFormat
 from repro.core import packing as pk
 from . import autotune, ref, substrate
-from .vp_attention import flash_prefill_pallas, vp_decode_attention_pallas
+from .vp_attention import (
+    flash_prefill_pallas,
+    vp_decode_attention_pallas,
+    vp_paged_decode_attention_pallas,
+)
 from .vp_quant import vp_quant_pallas, vp_quant_packed_pallas
 from .vp_dequant import vp_dequant_pallas, vp_dequant_packed_pallas
 from .vp_dequant_matmul import vp_dequant_matmul_pallas
@@ -847,6 +851,82 @@ def vp_decode_attention(
     out = vp_decode_attention_pallas(
         qr, kw, vw, ks, vs, lengths.astype(jnp.int32), fmt,
         window=window, rolling=rolling, bs=bs, smax=Smax,
+        interpret=(backend == "interpret"))
+    return out[:, :, :G].reshape(B, 1, H, dh).astype(q.dtype)
+
+
+# A page's scales ride one 128-lane row of the paged decode kernel.
+_SCALE_ROW = 128
+
+
+def paged_decode_supported(page_size: int) -> bool:
+    """Whether `vp_paged_decode_attention` reads pages of this size in
+    place: a page's scales must tile one 128-lane row."""
+    return _SCALE_ROW % page_size == 0
+
+
+def vp_paged_decode_attention(
+    q, k_pool, v_pool, k_s_pool, v_s_pool, k_tail, v_tail, k_tail_s,
+    v_tail_s, layer, block_table, base, lengths,
+    fmt: VPFormat,
+    window: Optional[int] = None,
+    pages_per_block: int = 16,
+    interpret: Optional[bool] = None,
+):
+    """Single-token decode attention that reads the KV pages in place.
+
+    q (B, 1, H, dh); k_pool / v_pool (L, n_pages, page, KV, dh) the
+    whole stacked pools of packed VP words and k_s_pool / v_s_pool
+    (L, n_pages, page, 1, 1) their pow2 scales (`serving.page_cache`);
+    k_tail / v_tail (B, T, KV, dh) and k_tail_s / v_tail_s (B, T, 1, 1)
+    the positions base .. base + T - 1 still in flight (this step's
+    among them); layer () int32 indexes the pools; block_table (B, P);
+    base (B,) positions already in the pools; lengths (B,) valid
+    positions, pools and tail together.
+
+    On a kernel backend the pools never leave HBM whole: the kernel
+    copies only the pages holding [window bound, base) of each row,
+    `pages_per_block` at a time, through the scalar-prefetched block
+    table.  The word pools are passed whole — slicing one layer out of
+    them in XLA would copy it.  The ref backend gathers this layer's
+    pages and runs the contiguous oracle.
+    """
+    contracts.require_format_serviceable(fmt, "vp_decode_attention")
+    backend = substrate.resolve_backend(interpret)
+    if backend == "ref":
+        return ref.vp_paged_decode_attention_ref(
+            q, k_pool, v_pool, k_s_pool, v_s_pool, k_tail, v_tail,
+            k_tail_s, v_tail_s, layer, block_table, base, lengths, fmt,
+            window=window)
+    B, _, H, dh = q.shape
+    n_pages, page, KV = k_pool.shape[1], k_pool.shape[2], k_pool.shape[3]
+    T = k_tail.shape[1]
+    G = H // KV
+    if not paged_decode_supported(page):
+        raise ValueError(
+            f"paged decode attention needs a page size that divides "
+            f"{_SCALE_ROW}, got {page}")
+    per = _SCALE_ROW // page
+    npg = -(-pages_per_block // per) * per   # whole 128-lane scale rows
+
+    def scale_rows(s_pool):
+        # this layer's scales, a page's `page` scales tiled across one
+        # 128-lane row, so one DMA brings a page's scales
+        s = jax.lax.dynamic_index_in_dim(s_pool, layer, 0, keepdims=False)
+        return jnp.tile(s.reshape(n_pages, 1, page), (1, 1, per))
+
+    def tail(w):
+        return w.reshape(B, T, KV * dh).astype(jnp.int32)
+
+    qr = q.reshape(B, KV, G, dh).astype(jnp.float32) * dh ** -0.5
+    gp = max(G, 8) if backend == "native" else G
+    if gp != G:
+        qr = jnp.pad(qr, ((0, 0), (0, 0), (0, gp - G), (0, 0)))
+    out = vp_paged_decode_attention_pallas(
+        qr, k_pool, v_pool, scale_rows(k_s_pool), scale_rows(v_s_pool),
+        tail(k_tail), tail(v_tail), k_tail_s.reshape(B, T, 1),
+        v_tail_s.reshape(B, T, 1), layer, block_table, base, lengths, fmt,
+        window=window, pages_per_block=npg,
         interpret=(backend == "interpret"))
     return out[:, :, :G].reshape(B, 1, H, dh).astype(q.dtype)
 

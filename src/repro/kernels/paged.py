@@ -7,24 +7,23 @@ and eviction are then pure metadata — pages change owner by index, and
 the packed VP words inside them are NEVER copied or dequantized when
 requests come and go.
 
-These ops are the only code that touches the pool layout:
+These ops are the XLA side of the pool layout:
 
   * `gather_pages`    — block table -> contiguous per-request view
-                        (what `vp_decode_attention` / the jnp ref core
-                        consume, masked by the scalar-prefetched
-                        per-request `lengths`)
+                        (what prefill chunks, the planes/float golden
+                        caches and the mesh runner's decode consume,
+                        masked by the per-request `lengths`)
   * `scatter_pages`   — write whole pages (prefill commits a prompt)
-  * `scatter_positions` — write single positions (decode commits one
-                        token per request; chunked prefill commits a
-                        chunk that may straddle pages)
+  * `scatter_positions` — write single positions (decode commits its
+                        new tokens per request; chunked prefill commits
+                        a chunk that may straddle pages)
 
 On the jnp/ref backend these lower to one XLA gather / scatter over the
-page axis.  On the TPU-native backend the same block-table row becomes
-the scalar-prefetch argument of the Pallas decode kernel (the kernel
-DMAs pages by id instead of gathering a contiguous view in HBM first) —
-that lowering rides the existing `vp_decode_attention` grid and is
-tracked in ROADMAP open item 1's follow-up; every caller goes through
-this module so the swap is local.
+page axis.  Single-device decode over a packed-word cache gathers
+nothing: `kernels.ops.vp_paged_decode_attention` takes the whole pools
+and the block table, and its Pallas kernel DMAs each request's live
+pages by id through the scalar-prefetched table (its ref oracle gathers
+one layer's pages with `gather_pages`).
 
 Page 0 is reserved as the DUMMY page: free-list allocation never hands
 it out, and masked writes (inactive batch rows) land there.  Nothing

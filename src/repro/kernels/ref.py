@@ -369,6 +369,35 @@ def vp_decode_attention_ref(
     return _decode_attention_core(q, kr, vr, lengths, window, rolling)
 
 
+@functools.partial(jax.jit, static_argnames=("fmt", "window"))
+def vp_paged_decode_attention_ref(
+    q, k_pool, v_pool, k_s_pool, v_s_pool, k_tail, v_tail, k_tail_s,
+    v_tail_s, layer, block_table, base, lengths,
+    fmt: VPFormat,
+    window: Optional[int] = None,
+):
+    """Paged packed-KV decode oracle: layer `layer`'s pages gathered
+    through the block table into a contiguous view, the in-flight tail
+    written at `base`, then `vp_decode_attention_ref`.
+
+    This is the computation a gathered-view decode step performs, so the
+    paged path is bit-identical to it on this backend: positions past
+    `lengths` differ (stale pages, an unfilled tail) but contribute
+    exact zeros.
+    """
+    from .paged import gather_pages
+
+    def view(pool, tail):
+        cache = gather_pages(pool[layer][None], block_table)[0]
+        return jax.vmap(lambda c, t, j: jax.lax.dynamic_update_slice_in_dim(
+            c, t, j, axis=0))(cache, tail, base)
+
+    return vp_decode_attention_ref(
+        q, view(k_pool, k_tail), view(v_pool, v_tail),
+        view(k_s_pool, k_tail_s), view(v_s_pool, v_tail_s), lengths, fmt,
+        window=window)
+
+
 @functools.partial(jax.jit, static_argnames=("pattern", "window"))
 def flash_prefill_ref(q, k, v, pattern: str = "causal",
                       window: Optional[int] = None):

@@ -47,6 +47,21 @@ def vmem(shape: Tuple[int, ...], dtype):
     return pltpu.VMEM(shape, dtype)
 
 
+def smem(shape: Tuple[int, ...], dtype):
+    """SMEM scratch allocation (scalars that persist across grid steps)."""
+    return pltpu.SMEM(shape, dtype)
+
+
+def dma_semaphores(n: int):
+    """`n` DMA-completion semaphores as one scratch allocation."""
+    return pltpu.SemaphoreType.DMA((n,))
+
+
+def async_copy(src, dst, sem):
+    """An HBM -> VMEM copy descriptor (`.start()` / `.wait()`)."""
+    return pltpu.make_async_copy(src, dst, sem)
+
+
 def vp_pallas_call(
     kernel,
     *,

@@ -416,7 +416,35 @@ def attn_block(
                              len=cache["len"] + S)
         out = out.reshape(*x.shape[:-1], H * dh)
         return qdot(out, params["wo"], q_cfg, train), new_cache
-    if cache is not None and kv_override is None:
+    if cache is not None and kv_override is None and "pages" in cache:
+        # Paged decode (`serving.runner`): the cache is the WHOLE stacked
+        # page pools, the block table and this layer's index, plus a
+        # small tail of the positions not yet committed to the pools.
+        # This step's K/V join the tail; the op reads the committed
+        # positions page by page, so no contiguous view is built.
+        pages = cache["pages"]
+        idx = cache["len"]  # (B,)
+        # Tail slot of this step per row, written by a select: a batched
+        # dynamic_update_slice becomes a loop over rows on TPU.
+        at = idx - pages["base"]
+        hit = jnp.arange(cache["k_w"].shape[1])[None] == at[:, None]
+        upd = lambda buf, val: jnp.where(
+            hit.reshape(hit.shape + (1,) * (buf.ndim - 2)),
+            val.astype(buf.dtype), buf)
+        with jax.named_scope("kv_append"):
+            w_k, s_k = quantize_kv(kp, q_cfg)
+            w_v, s_v = quantize_kv(vp_, q_cfg)
+            new_cache = dict(
+                k_w=upd(cache["k_w"], w_k), k_s=upd(cache["k_s"], s_k),
+                v_w=upd(cache["v_w"], w_v), v_s=upd(cache["v_s"], s_v),
+                len=idx + kp.shape[1])
+        _, vp_fmt = kv_cache_formats(q_cfg)
+        out = kops.vp_paged_decode_attention(
+            qp, pages["k_w"], pages["v_w"], pages["k_s"], pages["v_s"],
+            new_cache["k_w"], new_cache["v_w"], new_cache["k_s"],
+            new_cache["v_s"], cache["layer"], pages["block_table"],
+            pages["base"], new_cache["len"], vp_fmt, window=window)
+    elif cache is not None and kv_override is None:
         # Decode: append this step's K/V.  A buffer no longer than the
         # sliding window acts as a ring buffer (long-context SWA decode).
         smax = _cache_buf(cache).shape[1]

@@ -380,18 +380,30 @@ def _scan_group(x, group_params, cfg, patterns, positions, shared=None,
     """Scan a homogeneous group of layers.
 
     group_params: {"sub{j}": stacked-params} (leading axis = repeats).
-    caches: matching stacked cache pytree or None.
+    caches: matching stacked cache pytree or None.  A sub-cache's
+    "pages" entry (paged decode, `serving.runner`) holds whole stacked
+    page pools: it rides outside the scan, which would otherwise slice
+    (copy) one layer's pool per step, and each layer finds it beside its
+    layer index "layer".
     Returns (x, new_caches, aux_sum).
     """
+    held = {s: c["pages"] for s, c in (caches or {}).items()
+            if "pages" in c}
+    if held:
+        caches = {s: {k: v for k, v in c.items() if k != "pages"}
+                  for s, c in caches.items()}
+
     def body(carry, per_layer):
         h = carry
         h = _maybe_shard_seq(h, cfg)
-        p_layer, cache_layer = per_layer
+        p_layer, cache_layer, layer = per_layer
         new_caches = {}
         aux_acc = jnp.zeros((2,), jnp.float32)
         for j, pattern in enumerate(patterns):
             p_sub = shared if pattern == "shared_attn" else p_layer[f"sub{j}"]
             c_in = None if cache_layer is None else cache_layer.get(f"sub{j}")
+            if f"sub{j}" in held:
+                c_in = {**c_in, "pages": held[f"sub{j}"], "layer": layer}
             h, c_out, aux = _apply_sublayer(
                 h, p_sub, cfg, pattern, positions, cache=c_in, train=train,
                 chunked=chunked)
@@ -403,11 +415,11 @@ def _scan_group(x, group_params, cfg, patterns, positions, shared=None,
     n_rep = jax.tree_util.tree_leaves(group_params)[0].shape[0]
     if cfg.remat == "full":
         body = jax.checkpoint(body)
-    if caches is None:
-        caches_xs = None
-    else:
-        caches_xs = caches
-    x, (new_caches, aux) = jax.lax.scan(body, x, (group_params, caches_xs))
+    layers = jnp.arange(n_rep, dtype=jnp.int32) if held else None
+    x, (new_caches, aux) = jax.lax.scan(body, x,
+                                        (group_params, caches, layers))
+    for s, pages in held.items():
+        new_caches[s]["pages"] = pages
     return x, new_caches, aux.sum(0)
 
 

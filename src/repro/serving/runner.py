@@ -3,22 +3,28 @@
 Every step is one compiled function with a fixed shape signature:
 
   gather  — block-table rows -> contiguous per-slot cache views
-            (`kernels.paged.gather_pages`; dense ring/SSM rows slice
-            directly).  The view length is the FULL slot capacity, so
-            one decode compile serves every mix of request lengths —
-            positions past a slot's `lengths` entry are masked to
-            exactly-zero softmax terms by the attention cores, which is
-            what keeps engine logits bit-identical to the static driver
-            on the ref backend.
+            (`build_view`: `kernels.paged.gather_pages`; dense ring/SSM
+            rows slice directly).  The view length is the FULL slot
+            capacity, so one compile serves every mix of request
+            lengths — positions past a slot's `lengths` entry are masked
+            to exactly-zero softmax terms by the attention cores.
+            Prefill (whole and chunked) gathers; so does decode over the
+            planes/float golden caches and under a mesh.  Single-device
+            decode over packed words gathers NOTHING
+            (`paged_decode_caches`): the model receives the whole pools
+            with the block table, and `vp_paged_decode_attention` reads
+            each row's live pages in place; the positions a call
+            appends ride a small per-layer tail.
   compute — the UNCHANGED model functions (`prefill` / `decode_step`):
-            the paged engine adds no second model implementation, and
-            the packed-KV view flows through the same
-            `vp_decode_attention` op (its scalar-prefetched `lengths`
-            carries the ragged per-request spans).
+            the paged engine adds no second model implementation; the
+            attention block picks the paged or contiguous op from the
+            cache entry it is handed.  On the ref backend both compute
+            the same thing, so engine logits stay bit-identical to the
+            static driver.
   commit  — scatter ONLY the newly written positions back to the pools
-            (one token per slot at decode; whole pages at prefill) and
-            write back dense rows/states.  Nothing else in the cache is
-            copied or dequantized.
+            (the tail's `steps` tokens per slot at decode; whole pages at
+            prefill) and write back dense rows/states.  Nothing else in
+            the cache is copied or dequantized.
   sample  — argmax / categorical INSIDE the jitted step, so the decode
             wall-clock measures the model, not a host-side Python
             sampling loop.
@@ -30,16 +36,19 @@ commits are masked to the dummy page / their own old rows.
 Each program is named for its shapes (`decode_b{Bp}_s{steps}`,
 `prefill_s{S}`, `chunk_c{C}`; the compiled module is `jit_<name>`) and
 counted in `compiles.<name>` when built; the stages above are named
-scopes (`gather`, `model`, `sample`, `commit`), which label the compiled
-operations and change nothing else.  Each call is a `repro.tracing`
-span with `dispatch`, `wait` and `fetch` children.
+scopes (`gather`, `model`, `sample`, `commit`; the in-place decode has
+no `gather`), which label the compiled operations and change nothing
+else.  Each call is a `repro.tracing` span with `dispatch`, `wait` and
+`fetch` children; `runner.decode` says whether it read pages in place
+(`paged`) and how many pages its rows occupy (`pages`).
 
 Mesh-native serving: constructed with a `mesh`, the runner swaps the
 model calls for `parallel.shard_ops.sharded_forward_fns` — the SAME
 compute inside `shard_map`, weights tensor-parallel over the "model"
 axis (packed words sharded along d_out, outputs all-gathered), MoE
 experts expert-parallel.  Gather/commit stay global: pools, block
-tables and lengths are replicated, only the model forward shards.
+tables and lengths are replicated, only the model forward shards, and
+decode keeps the gathered view there.
 Decode buckets whose size divides the "data" axis additionally shard
 the batch dim over it (data-parallel-over-slots x tensor-parallel-over-
 weights); every collective on these paths is a concatenation, so served
@@ -57,7 +66,7 @@ import numpy as np
 
 from repro import tracing
 from repro.configs.base import ModelConfig
-from repro.kernels import paged
+from repro.kernels import ops as kops, paged
 from repro.models import decode_step, prefill
 from .page_cache import DENSE, PAGED, PagedKVCache, SubSpec, buf_key
 
@@ -103,6 +112,59 @@ def build_view(specs: Sequence[SubSpec], n_groups: int, pools, dense,
                 lens[None], (spec.reps, lens.shape[0]))
         caches[spec.gi][spec.sub] = entry
     return caches
+
+
+def paged_decode_caches(specs: Sequence[SubSpec], n_groups: int, pools,
+                        dense, block_table, lengths, slots, steps: int):
+    """The decode cache pytree that reads packed KV pages in place.
+
+    Each paged buffer becomes a zero tail of `steps` positions (what the
+    decode steps append, committed to the pools afterwards) beside one
+    "pages" entry: the WHOLE stacked pools, the slots' block-table rows
+    and their committed lengths ("base").  Nothing is gathered; the
+    attention op reads the pages through the block table.  Dense ring
+    buffers and SSM states slice their slot rows, as in `build_view`.
+    """
+    lens = lengths[slots]
+    B = lens.shape[0]
+    caches: List[dict] = [dict() for _ in range(n_groups)]
+    for spec in specs:
+        if spec.kind == PAGED:
+            entry = {name: jnp.zeros((spec.reps, B, steps) + tail, dtype)
+                     for name, tail, dtype in spec.bufs}
+            entry["pages"] = dict(
+                {name: pools[buf_key(spec, name)]
+                 for name, _, _ in spec.bufs},
+                block_table=block_table[slots], base=lens)
+        else:
+            entry = {name: dense[buf_key(spec, name)][:, slots]
+                     for name, _, _ in spec.bufs}
+        if spec.has_len:
+            entry["len"] = jnp.broadcast_to(lens[None], (spec.reps, B))
+        caches[spec.gi][spec.sub] = entry
+    return caches
+
+
+def _take_pages(caches):
+    """(caches without their "pages" entries, those entries by
+    (group, sub)): the pools stay out of the decode scan's carry."""
+    pages = {}
+    rest = []
+    for gi, group in enumerate(caches):
+        rest.append({})
+        for sub, entry in group.items():
+            if "pages" in entry:
+                pages[gi, sub] = entry["pages"]
+                entry = {k: v for k, v in entry.items() if k != "pages"}
+            rest[gi][sub] = entry
+    return rest, pages
+
+
+def _put_pages(caches, pages):
+    return [{sub: ({**entry, "pages": pages[gi, sub]}
+                   if (gi, sub) in pages else entry)
+             for sub, entry in group.items()}
+            for gi, group in enumerate(caches)]
 
 
 # Compiled prefill/decode for the degradation oracle, keyed by config
@@ -175,6 +237,15 @@ class ModelRunner:
         else:
             self._dp = 1
         self._sharded_fns = None
+        # Decode reads packed KV pages in place (`paged_decode_caches`)
+        # unless the model shards under a mesh, the cache is planes or
+        # floats (the golden baselines), or the kernel cannot tile the
+        # page size; those keep the gathered view (`build_view`).
+        self.paged_decode = mesh is None \
+            and kops.paged_decode_supported(kv.page_size) \
+            and any(spec.kind == PAGED and any(
+                name == "k_w" for name, _, _ in spec.bufs)
+                for spec in kv.specs)
         # Donation lets XLA update pools in place; CPU ignores it (and
         # warns), so only request it off-CPU.
         self._donate = jax.default_backend() != "cpu"
@@ -319,64 +390,82 @@ class ModelRunner:
         return self._jit(fn, f"chunk_c{C}", donate=(2, 3, 5))
 
     def _make_decode(self, Bp: int, n_steps: int):
-        """Fused decode: gather the slot views ONCE, run `n_steps`
-        feedback decode steps inside one `lax.scan`, scatter the
-        `n_steps` new positions per slot once at the end.
+        """Fused decode: `n_steps` feedback decode steps inside one
+        `lax.scan`, the `n_steps` new positions per slot scattered to
+        the pools once at the end.
 
-        Each in-scan step is the UNCHANGED `decode_step` on the same
-        contiguous view a single-step call would see (the view after an
-        in-view append is elementwise identical to scatter-then-regather)
-        so the emitted logits are bit-identical to `n_steps` separate
-        calls — run-ahead buys dispatch/gather/scatter amortization, not
-        different math."""
+        On the in-place path (`self.paged_decode`) the steps read the
+        committed positions straight out of the pools through the block
+        table (`paged_decode_caches`); the positions they append ride a
+        small per-layer tail in the scan's carry, and the layer scan
+        emits only that tail, so nothing cache-sized is built, carried
+        or written back.  Otherwise the slot views are gathered ONCE
+        (`build_view`) and each step is the unchanged `decode_step` on
+        the same contiguous view a single-step call would see (the view
+        after an in-view append is elementwise identical to
+        scatter-then-regather).  Either way the emitted logits are those
+        of `n_steps` separate calls — run-ahead buys dispatch and commit
+        amortization, not different math."""
         kv = self.kv
         ps = kv.page_size
         temperature = self.temperature
+        in_place = self.paged_decode
 
         batch_sharded = self._dp > 1 and Bp % self._dp == 0
 
         def fn(params, tokens, pools, dense, block_table, lengths, slots,
                active, key):
-            with jax.named_scope("gather"):
-                view = build_view(kv.specs, kv.group_count, pools, dense,
-                                  block_table, lengths, slots)
+            if in_place:
+                caches, pages = _take_pages(paged_decode_caches(
+                    kv.specs, kv.group_count, pools, dense, block_table,
+                    lengths, slots, n_steps))
+            else:
+                with jax.named_scope("gather"):
+                    caches = build_view(kv.specs, kv.group_count, pools,
+                                        dense, block_table, lengths, slots)
+                pages = {}
             _, decode_fn = self._model_fns(params)
 
             def body(carry, i):
                 toks, caches = carry
-                logits, caches = decode_fn(params, toks, caches,
-                                           batch_sharded=batch_sharded)
+                logits, caches = decode_fn(
+                    params, toks, _put_pages(caches, pages),
+                    batch_sharded=batch_sharded)
+                caches, _ = _take_pages(caches)
                 with jax.named_scope("sample"):
                     nxt = _sample(logits, jax.random.fold_in(key, i),
                                   temperature)
                 return (nxt, caches), (nxt, logits)
 
             with jax.named_scope("model"):
-                (_, view), (nxts, logits) = jax.lax.scan(
-                    body, (tokens, view),
+                (_, caches), (nxts, logits) = jax.lax.scan(
+                    body, (tokens, caches),
                     jnp.arange(n_steps, dtype=jnp.int32))
             with jax.named_scope("commit"):
                 pos0 = jnp.where(active, lengths[slots], 0)
                 idxs = pos0[:, None] + jnp.arange(
                     n_steps, dtype=jnp.int32)[None]
                 for spec in kv.specs:
-                    entry = view[spec.gi][spec.sub]
+                    entry = caches[spec.gi][spec.sub]
                     if spec.kind == PAGED:
                         # Inactive rows scatter to the dummy page 0;
                         # nothing reads it, so collisions there are
                         # harmless.
-                        pages = jnp.where(
+                        page_ids = jnp.where(
                             active[:, None],
                             jnp.take_along_axis(block_table[slots],
                                                 idxs // ps, axis=1), 0)
                         for name, tail, _ in spec.bufs:
                             k = buf_key(spec, name)
-                            idx = idxs.reshape(
-                                (1, Bp, n_steps) + (1,) * len(tail))
-                            val = jnp.take_along_axis(entry[name], idx,
-                                                      axis=2)
+                            if in_place:   # the tail IS the new positions
+                                val = entry[name]
+                            else:
+                                idx = idxs.reshape(
+                                    (1, Bp, n_steps) + (1,) * len(tail))
+                                val = jnp.take_along_axis(
+                                    entry[name], idx, axis=2)
                             pools[k] = paged.scatter_positions(
-                                pools[k], pages, idxs % ps, val)
+                                pools[k], page_ids, idxs % ps, val)
                     else:
                         for name, _, _ in spec.bufs:
                             k = buf_key(spec, name)
@@ -430,6 +519,7 @@ class ModelRunner:
                 nxt, logits, kv.pools, kv.dense, kv.lengths = self._call(
                     fn, (params, tokens, kv.pools, kv.dense, bt_row,
                          kv.lengths, jnp.int32(slot), key))
+                kv.host_lengths[slot] = S
             self._wait("runner.prefill", (nxt, logits))
             with tracing.span("runner.prefill.fetch"):
                 nxt = np.asarray(nxt)
@@ -451,6 +541,7 @@ class ModelRunner:
                 nxt, logits, kv.pools, kv.dense, kv.lengths = self._call(
                     fn, (params, tokens, kv.pools, kv.dense,
                          kv.block_table, kv.lengths, jnp.int32(slot), key))
+                kv.host_lengths[slot] += C
             self._wait("runner.prefill", (nxt, logits))
             with tracing.span("runner.prefill.fetch"):
                 nxt = np.asarray(nxt)
@@ -478,8 +569,13 @@ class ModelRunner:
             Bp <<= 1
         Bp = min(Bp, kv.max_slots) if Bp > len(act) else Bp
         fn = self._decode_fns.get((Bp, steps))
+        # `pages`: the pages the active rows occupy once the call is
+        # done, which the in-place path reads; 0 on the gathered path.
+        pages = sum(-(-(int(kv.host_lengths[s]) + steps) // kv.page_size)
+                    for s in act) if self.paged_decode else 0
         with tracing.span("runner.decode", rows=len(act), Bp=Bp,
-                          steps=steps, first=fn is None):
+                          steps=steps, first=fn is None,
+                          paged=self.paged_decode, pages=pages):
             with tracing.span("runner.decode.dispatch"):
                 pad = [s for s in range(kv.max_slots)
                        if s not in slot_tokens]
@@ -499,6 +595,7 @@ class ModelRunner:
                          jnp.asarray(np.asarray(tokens, np.int32)[:, None]),
                          kv.pools, kv.dense, kv.block_table, kv.lengths,
                          comp[0], comp[1], key))
+                kv.host_lengths[act] += steps
             self._wait("runner.decode", (nxt, logits))
             with tracing.span("runner.decode.fetch"):
                 nxt_h = np.asarray(nxt)          # (steps, Bp, 1)

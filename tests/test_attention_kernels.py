@@ -373,3 +373,106 @@ def test_resolve_attn_blocks_cache_roundtrip(tmp_path, monkeypatch):
     np.testing.assert_allclose(np.asarray(out_tuned),
                                np.asarray(out_explicit),
                                rtol=1e-6, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# Paged decode: pages read in place through the block table
+# ---------------------------------------------------------------------------
+
+# Pools of L layers x N pages of PS positions; rows of P pages (capacity
+# P * PS).  Pages of 32 give 4 pages a 128-lane scale row, so a block is
+# 4 pages and a row of 8 pages takes two blocks.
+L_, N_, PS_, P_ = 2, 40, 32, 8
+CAP_ = P_ * PS_
+
+
+def _paged_inputs(steps, G, seed=0):
+    """Pools, a shuffled block table and ragged rows: committed lengths
+    on and off page boundaries, one at capacity - steps, and a parked
+    padding row (all-dummy block table, nothing committed)."""
+    B, KV, dh = 6, 2, 32
+    rng = np.random.default_rng(seed)
+    key = jax.random.PRNGKey(seed)
+    k1, k2, k3, k4, kq = jax.random.split(key, 5)
+    k_w, k_s = quantize_kv(
+        jax.random.normal(k1, (L_ * N_, PS_, KV, dh)) * 2.0, KVQ)
+    v_w, v_s = quantize_kv(jax.random.normal(k2, (L_ * N_, PS_, KV, dh)),
+                           KVQ)
+    pools = [a.reshape((L_, N_) + a.shape[1:]) for a in (k_w, v_w, k_s, v_s)]
+    t_kw, t_ks = quantize_kv(jax.random.normal(k3, (B, steps, KV, dh)), KVQ)
+    t_vw, t_vs = quantize_kv(jax.random.normal(k4, (B, steps, KV, dh)), KVQ)
+    base = np.array([1, PS_, PS_ + 1, 5 * PS_ - 1, CAP_ - steps, 0],
+                    np.int32)
+    fill = rng.integers(1, steps + 1, size=B).astype(np.int32)
+    fill[4] = steps
+    # live pages come shuffled from 1 .. N - 9; the last 8 ids are the
+    # stale ones the block tables' unused tails point at
+    ids = rng.permutation(np.arange(1, N_ - 8))
+    bt = np.zeros((B, P_), np.int32)
+    used = 0
+    for r in range(B - 1):
+        n = -(-int(base[r]) // PS_)
+        bt[r, :n] = ids[used:used + n]
+        bt[r, n:] = rng.integers(N_ - 8, N_, size=P_ - n)
+        used += n
+    q = jax.random.normal(kq, (B, 1, KV * G, dh), jnp.float32)
+    return (q, *pools, t_kw, t_vw, t_ks, t_vs, jnp.int32(1),
+            jnp.asarray(bt), jnp.asarray(base), jnp.asarray(base + fill))
+
+
+def _poisoned(args):
+    """The dummy page and every page only a stale block-table entry
+    names, in every layer: extreme words, NaN scales."""
+    bad = jnp.asarray([0] + list(range(N_ - 8, N_)))
+    q, k_w, v_w, k_s, v_s, *rest = args
+    top = jnp.iinfo(k_w.dtype).max
+    return (q, k_w.at[:, bad].set(top), v_w.at[:, bad].set(-top - 1),
+            k_s.at[:, bad].set(jnp.nan), v_s.at[:, bad].set(jnp.nan), *rest)
+
+
+@pytest.mark.parametrize("steps,G,window", [
+    (1, 1, None),
+    (4, 2, None),
+    (1, 2, 40),
+    (4, 1, 70),
+])
+def test_paged_decode_kernel_interpret_parity(steps, G, window):
+    """The paged kernel body (interpreter) == the gather-plus-contiguous
+    oracle, and reads no page outside a row's live span: poisoning the
+    dummy page and the stale block-table entries changes no bit."""
+    _, vp = kv_cache_formats(KVQ)
+    args = _paged_inputs(steps, G)
+    want = kref.vp_paged_decode_attention_ref(*args, vp, window=window)
+    got = ops.vp_paged_decode_attention(*args, vp, window=window,
+                                        pages_per_block=4, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    poisoned = ops.vp_paged_decode_attention(
+        *_poisoned(args), vp, window=window, pages_per_block=4,
+        interpret=True)
+    np.testing.assert_array_equal(np.asarray(poisoned), np.asarray(got))
+
+
+@pytest.mark.parametrize("steps", [1, 4])
+def test_paged_decode_ref_is_the_gathered_view(steps):
+    """On the ref backend the paged op IS the contiguous op on the view
+    the runner used to gather: bit for bit, so engine logits stay
+    identical to the static driver's."""
+    _, vp = kv_cache_formats(KVQ)
+    (q, k_w, v_w, k_s, v_s, t_kw, t_vw, t_ks, t_vs, layer, bt, base,
+     lens) = _paged_inputs(steps, 2, seed=3)
+
+    def view(pool, tail):
+        cache = pool[1][bt].reshape((bt.shape[0], CAP_) + pool.shape[3:])
+        for r in range(bt.shape[0]):
+            cache = cache.at[r, int(base[r]):int(base[r]) + steps].set(
+                tail[r])
+        return cache
+
+    want = kref.vp_decode_attention_ref(
+        q, view(k_w, t_kw), view(v_w, t_vw), view(k_s, t_ks),
+        view(v_s, t_vs), lens, vp)
+    got = kref.vp_paged_decode_attention_ref(
+        q, k_w, v_w, k_s, v_s, t_kw, t_vw, t_ks, t_vs, layer, bt, base,
+        lens, vp)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

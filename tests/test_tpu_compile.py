@@ -111,3 +111,81 @@ def test_equalizer_kernel_compiles(compile_native):
         lambda w, y: equalize_vp_kernel(spec, w, y, fused=True),
         ((n, U, B), jnp.complex64), ((n, B), jnp.complex64))
     assert "tpu_custom_call" in hlo
+
+
+# The serving cell's paged cache: 28 layers of pools, pages of 16,
+# capacity 2048 at 16 slots (16 x 128 pages and the dummy page).
+LAYERS, PAGE, CAP, ROWS = 28, 16, 2048, 16
+
+
+@pytest.mark.parametrize("steps", [1, 4])
+def test_vp_paged_decode_attention_compiles(compile_native, steps):
+    _, vp = kv_cache_formats(QuantConfig(mode="vp", quantize_kv_cache=True))
+    n_pages = 1 + ROWS * CAP // PAGE
+    words = ((LAYERS, n_pages, PAGE, KV, DH), storage_dtype(vp))
+    scales = ((LAYERS, n_pages, PAGE, 1, 1), jnp.float32)
+    tail = ((ROWS, steps, KV, DH), storage_dtype(vp))
+    tail_s = ((ROWS, steps, 1, 1), jnp.float32)
+    rows = ((ROWS,), jnp.int32)
+    hlo = compile_native(
+        lambda *a: ops.vp_paged_decode_attention(*a, vp),
+        ((ROWS, 1, H, DH), jnp.bfloat16), words, words, scales, scales,
+        tail, tail, tail_s, tail_s, ((), jnp.int32),
+        ((ROWS, CAP // PAGE), jnp.int32), rows, rows)
+    assert "tpu_custom_call" in hlo
+    # the pools reach the kernel whole: no copy of a layer's words
+    assert f"[1,{n_pages},{PAGE},{KV},{DH}]" not in hlo
+
+
+def _decode_program(one_chip, in_place: bool):
+    """The runner's `decode_b16_s1` program of a two-layer model at
+    qwen3-0.6b's head widths, compiled for one described chip."""
+    from repro.configs.base import ModelConfig
+    from repro.models import init_params, quantize_params
+    from repro.serving.page_cache import PagedKVCache
+    from repro.serving.runner import ModelRunner
+
+    cfg = ModelConfig(name="two-layer", family="dense", n_layers=2,
+                      d_model=256, n_heads=4, n_kv_heads=2, d_head=DH,
+                      d_ff=512, vocab=512, qk_norm=True,
+                      quant=QuantConfig(mode="vp", quantize_kv_cache=True,
+                                        kv_layout="packed"))
+    kv = PagedKVCache(cfg, max_slots=ROWS, capacity=256, page_size=PAGE)
+    runner = ModelRunner(cfg, kv)
+    assert runner.paged_decode
+    runner.paged_decode = in_place
+    params = jax.eval_shape(lambda: quantize_params(
+        init_params(jax.random.PRNGKey(0), cfg), cfg))
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    args = jax.tree_util.tree_map(spec, (
+        params, jnp.zeros((ROWS, 1), jnp.int32), kv.pools, kv.dense,
+        kv.block_table, kv.lengths, jnp.arange(ROWS, dtype=jnp.int32),
+        jnp.ones((ROWS,), bool), jax.random.PRNGKey(0)))
+    with substrate.force_backend("native"):
+        lowered = runner._make_decode(ROWS, 1).lower(*args)
+        return lowered.as_text(), lowered.compile().as_text()
+
+
+def test_paged_decode_program_gathers_no_view(compile_native, one_chip):
+    """The in-place decode program holds no capacity-length view of the
+    pools, before or after the TPU compiler; the gathered path, compiled
+    the same way, does (the check can see one)."""
+    view_hlo = f"s16[2,{ROWS},256,2,{DH}]"
+    view_mlir = f"tensor<2x{ROWS}x256x2x{DH}xi16>"
+    pool = f"tensor<2x{1 + ROWS * 256 // PAGE}x{PAGE}x"   # words, scales
+
+    def pool_gathers(text):
+        return [line for line in text.splitlines()
+                if "stablehlo.gather" in line and pool in line]
+
+    lowered, compiled = _decode_program(one_chip, in_place=True)
+    assert "tpu_custom_call" in compiled
+    assert "vp_paged_decode_attention" in compiled
+    assert view_mlir not in lowered and view_hlo not in compiled
+    assert pool_gathers(lowered) == []
+    lowered, compiled = _decode_program(one_chip, in_place=False)
+    assert view_mlir in lowered and view_hlo in compiled
+    assert len(pool_gathers(lowered)) == 4

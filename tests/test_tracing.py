@@ -203,8 +203,43 @@ def _scopes_of(text, scopes):
 def test_decode_text_maps_instructions_to_its_scopes(texts):
     scopes = ("gather", "model", "kv_append", "sample", "commit")
     found = _scopes_of(texts["decode_b2_s1"], scopes)
-    assert {"gather", "kv_append", "commit", "sample", "model"} <= found
+    assert {"kv_append", "commit", "sample", "model"} <= found
+    # packed pages are read in place: the decode gathers nothing
+    assert "gather" not in found
     assert "commit" in _scopes_of(texts["prefill_s4"], scopes)
+
+
+def test_gathered_decode_keeps_its_gather_scope():
+    """The planes cache (a golden baseline) keeps the gathered view."""
+    cfg = dataclasses.replace(CFG, quant=dataclasses.replace(
+        CFG.quant, kv_layout="planes"))
+    params = quantize_params(init_params(jax.random.PRNGKey(0), cfg), cfg)
+    eng = ServingEngine(params, cfg, max_slots=2, capacity=32, page_size=8,
+                        clock=VirtualClock())
+    assert not eng.runner.paged_decode
+    eng.submit([1, 2, 3], 2, 0.0)
+    eng.run()
+    found = _scopes_of(eng.runner.compiled_text()["decode_b1_s1"],
+                       ("gather", "model", "kv_append", "sample", "commit"))
+    assert {"gather", "kv_append", "commit", "sample", "model"} <= found
+
+
+def test_decode_span_counts_the_pages_it_reads(served):
+    """`runner.decode` says it read pages in place, and how many pages
+    its rows occupy after the call: ceil((cache length + steps) / page)
+    summed over the active rows, the cache length being the live tokens
+    less the one fed this step."""
+    eng, spans, _ = served
+    ps = eng.kv.page_size
+    by_call = {s.call: s for s in spans}
+    decodes = [s for s in spans if s.name == "runner.decode"]
+    assert decodes
+    for dec in decodes:
+        live = by_call[dec.parent].attrs["live"]
+        steps = dec.attrs["steps"]
+        assert dec.attrs["paged"] is True
+        assert dec.attrs["pages"] == sum(
+            -(-(n - 1 + steps) // ps) for n in live)
 
 
 def test_equalizer_text_maps_instructions_to_its_scopes():
