@@ -855,14 +855,35 @@ def vp_decode_attention(
     return out[:, :, :G].reshape(B, 1, H, dh).astype(q.dtype)
 
 
-# A page's scales ride one 128-lane row of the paged decode kernel.
+# A page's scales ride one 128-lane row of the paged decode kernel, and
+# a page's words are copied whole by DMA.
 _SCALE_ROW = 128
 
 
-def paged_decode_supported(page_size: int) -> bool:
-    """Whether `vp_paged_decode_attention` reads pages of this size in
-    place: a page's scales must tile one 128-lane row."""
-    return _SCALE_ROW % page_size == 0
+def _paged_layout_ok(page_size: int, kv_heads: int, head_dim: int,
+                     word_dtype, backend: str) -> bool:
+    if backend == "ref":      # gathers the pages: any layout
+        return True
+    pack = 32 // (jnp.dtype(word_dtype).itemsize * 8)
+    # The kernel reads a position's heads 32 bits at a time, and a
+    # page's scales tile one 128-lane row.
+    ok = _SCALE_ROW % page_size == 0 and kv_heads % pack == 0
+    if backend == "native":
+        # Mosaic copies a page only as whole tiles of the pool's HBM
+        # layout: 128-lane heads, and KV heads a power of two or a
+        # multiple of 8 (6 int16 or 12 int8 heads pad to 8 and fail).
+        ok = ok and head_dim == _SCALE_ROW and (
+            kv_heads & (kv_heads - 1) == 0 or kv_heads % 8 == 0)
+    return ok
+
+
+def paged_decode_supported(page_size: int, kv_heads: int, head_dim: int,
+                           word_dtype) -> bool:
+    """Whether `vp_paged_decode_attention` reads pages of this layout in
+    place on the backend in force: any layout on the ref backend (which
+    gathers), only the layouts the kernel tiles on the others."""
+    return _paged_layout_ok(page_size, kv_heads, head_dim, word_dtype,
+                            substrate.resolve_backend(None))
 
 
 def vp_paged_decode_attention(
@@ -902,10 +923,11 @@ def vp_paged_decode_attention(
     n_pages, page, KV = k_pool.shape[1], k_pool.shape[2], k_pool.shape[3]
     T = k_tail.shape[1]
     G = H // KV
-    if not paged_decode_supported(page):
+    if not _paged_layout_ok(page, KV, dh, k_pool.dtype, backend):
         raise ValueError(
-            f"paged decode attention needs a page size that divides "
-            f"{_SCALE_ROW}, got {page}")
+            f"paged decode attention cannot tile pages of {page} "
+            f"positions x {KV} heads x {dh} {k_pool.dtype} words on the "
+            f"{backend} backend")
     per = _SCALE_ROW // page
     npg = -(-pages_per_block // per) * per   # whole 128-lane scale rows
 

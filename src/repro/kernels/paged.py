@@ -11,15 +11,17 @@ These ops are the XLA side of the pool layout:
 
   * `gather_pages`    — block table -> contiguous per-request view
                         (what prefill chunks, the planes/float golden
-                        caches and the mesh runner's decode consume,
-                        masked by the per-request `lengths`)
+                        caches, the mesh runner's decode and decode over
+                        page layouts the paged kernel cannot tile
+                        consume, masked by the per-request `lengths`)
   * `scatter_pages`   — write whole pages (prefill commits a prompt)
   * `scatter_positions` — write single positions (decode commits its
                         new tokens per request; chunked prefill commits
                         a chunk that may straddle pages)
 
 On the jnp/ref backend these lower to one XLA gather / scatter over the
-page axis.  Single-device decode over a packed-word cache gathers
+page axis.  Single-device decode over a packed-word cache whose layout
+the kernel tiles (`kernels.ops.paged_decode_supported`) gathers
 nothing: `kernels.ops.vp_paged_decode_attention` takes the whole pools
 and the block table, and its Pallas kernel DMAs each request's live
 pages by id through the scalar-prefetched table (its ref oracle gathers

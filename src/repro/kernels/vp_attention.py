@@ -223,7 +223,8 @@ def _head_words(buf):
     head h of position t is row t * KV + h.  A packed type shares each
     32-bit row between `32 // bits` neighbouring heads: the block is
     read as uint32 rows with a stride of KV // packing, once per group
-    of heads, and each head's bits are shifted out, sign extended.
+    of heads, and each head's bits are shifted out, sign extended.  KV
+    must be a multiple of the packing (`ops.paged_decode_supported`).
     """
     n, ps, kv, dh = buf.shape
     bits = jnp.dtype(buf.dtype).itemsize * 8

@@ -376,22 +376,19 @@ def _maybe_shard_seq(x, cfg: ModelConfig):
 
 
 def _scan_group(x, group_params, cfg, patterns, positions, shared=None,
-                caches=None, train=False, chunked=False):
+                caches=None, train=False, chunked=False, pages=None):
     """Scan a homogeneous group of layers.
 
     group_params: {"sub{j}": stacked-params} (leading axis = repeats).
-    caches: matching stacked cache pytree or None.  A sub-cache's
-    "pages" entry (paged decode, `serving.runner`) holds whole stacked
-    page pools: it rides outside the scan, which would otherwise slice
-    (copy) one layer's pool per step, and each layer finds it beside its
-    layer index "layer".
+    caches: matching stacked cache pytree or None.
+    pages: {"sub{j}": whole stacked page pools with the block table}
+    for paged decode (`serving.runner.paged_decode_caches`) or None.
+    They ride outside the scan, which would otherwise slice (copy) one
+    layer's pool per step; each layer finds them in its sub-cache as
+    "pages", beside its layer index "layer".
     Returns (x, new_caches, aux_sum).
     """
-    held = {s: c["pages"] for s, c in (caches or {}).items()
-            if "pages" in c}
-    if held:
-        caches = {s: {k: v for k, v in c.items() if k != "pages"}
-                  for s, c in caches.items()}
+    pages = pages or {}
 
     def body(carry, per_layer):
         h = carry
@@ -402,8 +399,8 @@ def _scan_group(x, group_params, cfg, patterns, positions, shared=None,
         for j, pattern in enumerate(patterns):
             p_sub = shared if pattern == "shared_attn" else p_layer[f"sub{j}"]
             c_in = None if cache_layer is None else cache_layer.get(f"sub{j}")
-            if f"sub{j}" in held:
-                c_in = {**c_in, "pages": held[f"sub{j}"], "layer": layer}
+            if f"sub{j}" in pages:
+                c_in = {**c_in, "pages": pages[f"sub{j}"], "layer": layer}
             h, c_out, aux = _apply_sublayer(
                 h, p_sub, cfg, pattern, positions, cache=c_in, train=train,
                 chunked=chunked)
@@ -415,11 +412,9 @@ def _scan_group(x, group_params, cfg, patterns, positions, shared=None,
     n_rep = jax.tree_util.tree_leaves(group_params)[0].shape[0]
     if cfg.remat == "full":
         body = jax.checkpoint(body)
-    layers = jnp.arange(n_rep, dtype=jnp.int32) if held else None
+    layers = jnp.arange(n_rep, dtype=jnp.int32) if pages else None
     x, (new_caches, aux) = jax.lax.scan(body, x,
                                         (group_params, caches, layers))
-    for s, pages in held.items():
-        new_caches[s]["pages"] = pages
     return x, new_caches, aux.sum(0)
 
 
@@ -490,8 +485,9 @@ def _decoder_backbone(params, x, cfg: ModelConfig, positions, cross_kv,
 
 
 def _lm_backbone(params, x, cfg: ModelConfig, positions, caches=None,
-                 train=False, chunked=False):
-    """Run all scanned groups.  caches: list aligned with groups or None."""
+                 train=False, chunked=False, pages=None):
+    """Run all scanned groups.  caches, pages: lists aligned with groups
+    or None (`_scan_group`)."""
     shared = params.get("shared_attn")
     new_caches = []
     aux = jnp.zeros((2,), jnp.float32)
@@ -499,7 +495,8 @@ def _lm_backbone(params, x, cfg: ModelConfig, positions, caches=None,
         c_in = None if caches is None else caches[gi]
         x, c_out, a = _scan_group(
             x, params["groups"][gi], cfg, group.patterns, positions,
-            shared=shared, caches=c_in, train=train, chunked=chunked)
+            shared=shared, caches=c_in, train=train, chunked=chunked,
+            pages=None if pages is None else pages[gi])
         new_caches.append(c_out)
         aux = aux + a
     return x, new_caches, aux
@@ -654,8 +651,11 @@ def init_cache(cfg: ModelConfig, B: int, max_len: int):
 
 
 def decode_step(params, token, caches, cfg: ModelConfig,
-                cross_kv=None):
-    """One decode step: token (B, 1) -> (logits (B, V), new caches)."""
+                cross_kv=None, pages=None):
+    """One decode step: token (B, 1) -> (logits (B, V), new caches).
+
+    pages: per group, the page pools that paged sub-caches read in place
+    (`_scan_group`), or None."""
     dtype = model_dtype(cfg)
     B = token.shape[0]
     x = embed_lookup(token, params["embed"], cfg.quant).astype(dtype)
@@ -672,7 +672,8 @@ def decode_step(params, token, caches, cfg: ModelConfig,
         new_caches = [new_caches]
     else:
         pos = _decode_positions(caches, cfg)
-        x, new_caches, _ = _lm_backbone(params, x, cfg, pos, caches=caches)
+        x, new_caches, _ = _lm_backbone(params, x, cfg, pos, caches=caches,
+                                        pages=pages)
     x = rms_norm(x, params["final_norm"])
     logits = qdot(x[:, 0], params["lm_head"], cfg.quant)
     return logits.astype(jnp.float32), new_caches

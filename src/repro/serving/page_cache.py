@@ -189,10 +189,6 @@ class PagedKVCache:
         self.block_table = jnp.zeros(
             (max_slots, self.pages_per_slot), jnp.int32)
         self.lengths = jnp.zeros((max_slots,), jnp.int32)
-        # The same lengths on the host, kept by whoever writes `lengths`
-        # (alloc/free here, the runner's steps), so that the host can
-        # count what a step reads without a device round trip.
-        self.host_lengths = np.zeros((max_slots,), np.int64)
 
         # Host-side allocator: LIFO free list over page ids 1..n_pages-1.
         self.free_pages: List[int] = list(range(self.n_pages - 1, 0, -1))
@@ -246,7 +242,6 @@ class PagedKVCache:
         row[:n] = pages
         self.block_table = self.block_table.at[slot].set(jnp.asarray(row))
         self.lengths = self.lengths.at[slot].set(0)
-        self.host_lengths[slot] = 0
         for spec in self.specs:
             if spec.kind == PAGED:
                 continue
@@ -267,7 +262,6 @@ class PagedKVCache:
         self.free_slots.append(slot)
         self.block_table = self.block_table.at[slot].set(0)
         self.lengths = self.lengths.at[slot].set(0)
-        self.host_lengths[slot] = 0
 
     # -- external pressure (fault injection / co-tenant reservations) -------
 

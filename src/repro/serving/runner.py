@@ -10,11 +10,13 @@ Every step is one compiled function with a fixed shape signature:
             to exactly-zero softmax terms by the attention cores.
             Prefill (whole and chunked) gathers; so does decode over the
             planes/float golden caches and under a mesh.  Single-device
-            decode over packed words gathers NOTHING
-            (`paged_decode_caches`): the model receives the whole pools
-            with the block table, and `vp_paged_decode_attention` reads
-            each row's live pages in place; the positions a call
-            appends ride a small per-layer tail.
+            decode over packed words whose page size and head layout the
+            kernel tiles (`kernels.ops.paged_decode_supported`) gathers
+            NOTHING (`paged_decode_caches`): the model receives the
+            whole pools with the block table beside its caches, and
+            `vp_paged_decode_attention` reads each row's live pages in
+            place; the positions a call appends ride a small per-layer
+            tail.
   compute — the UNCHANGED model functions (`prefill` / `decode_step`):
             the paged engine adds no second model implementation; the
             attention block picks the paged or contiguous op from the
@@ -116,23 +118,27 @@ def build_view(specs: Sequence[SubSpec], n_groups: int, pools, dense,
 
 def paged_decode_caches(specs: Sequence[SubSpec], n_groups: int, pools,
                         dense, block_table, lengths, slots, steps: int):
-    """The decode cache pytree that reads packed KV pages in place.
+    """(caches, pages): the decode cache pytree that reads packed KV
+    pages in place, and per group the pools it reads them from.
 
     Each paged buffer becomes a zero tail of `steps` positions (what the
-    decode steps append, committed to the pools afterwards) beside one
-    "pages" entry: the WHOLE stacked pools, the slots' block-table rows
-    and their committed lengths ("base").  Nothing is gathered; the
-    attention op reads the pages through the block table.  Dense ring
-    buffers and SSM states slice their slot rows, as in `build_view`.
+    decode steps append, committed to the pools afterwards); its sub's
+    `pages` entry holds the WHOLE stacked pools, the slots' block-table
+    rows and their committed lengths ("base"), passed beside the caches
+    (`models.model.decode_step`) so that no scan carries or slices them.
+    Nothing is gathered; the attention op reads the pages through the
+    block table.  Dense ring buffers and SSM states slice their slot
+    rows, as in `build_view`.
     """
     lens = lengths[slots]
     B = lens.shape[0]
     caches: List[dict] = [dict() for _ in range(n_groups)]
+    pages: List[dict] = [dict() for _ in range(n_groups)]
     for spec in specs:
         if spec.kind == PAGED:
             entry = {name: jnp.zeros((spec.reps, B, steps) + tail, dtype)
                      for name, tail, dtype in spec.bufs}
-            entry["pages"] = dict(
+            pages[spec.gi][spec.sub] = dict(
                 {name: pools[buf_key(spec, name)]
                  for name, _, _ in spec.bufs},
                 block_table=block_table[slots], base=lens)
@@ -142,29 +148,7 @@ def paged_decode_caches(specs: Sequence[SubSpec], n_groups: int, pools,
         if spec.has_len:
             entry["len"] = jnp.broadcast_to(lens[None], (spec.reps, B))
         caches[spec.gi][spec.sub] = entry
-    return caches
-
-
-def _take_pages(caches):
-    """(caches without their "pages" entries, those entries by
-    (group, sub)): the pools stay out of the decode scan's carry."""
-    pages = {}
-    rest = []
-    for gi, group in enumerate(caches):
-        rest.append({})
-        for sub, entry in group.items():
-            if "pages" in entry:
-                pages[gi, sub] = entry["pages"]
-                entry = {k: v for k, v in entry.items() if k != "pages"}
-            rest[gi][sub] = entry
-    return rest, pages
-
-
-def _put_pages(caches, pages):
-    return [{sub: ({**entry, "pages": pages[gi, sub]}
-                   if (gi, sub) in pages else entry)
-             for sub, entry in group.items()}
-            for gi, group in enumerate(caches)]
+    return caches, pages
 
 
 # Compiled prefill/decode for the degradation oracle, keyed by config
@@ -240,12 +224,14 @@ class ModelRunner:
         # Decode reads packed KV pages in place (`paged_decode_caches`)
         # unless the model shards under a mesh, the cache is planes or
         # floats (the golden baselines), or the kernel cannot tile the
-        # page size; those keep the gathered view (`build_view`).
-        self.paged_decode = mesh is None \
-            and kops.paged_decode_supported(kv.page_size) \
-            and any(spec.kind == PAGED and any(
-                name == "k_w" for name, _, _ in spec.bufs)
-                for spec in kv.specs)
+        # page and head layout on this backend; those keep the gathered
+        # view (`build_view`).
+        words = [(tail, dtype) for spec in kv.specs
+                 if spec.kind == PAGED
+                 for name, tail, dtype in spec.bufs if name == "k_w"]
+        self.paged_decode = mesh is None and bool(words) and all(
+            kops.paged_decode_supported(kv.page_size, *tail, dtype)
+            for tail, dtype in words)
         # Donation lets XLA update pools in place; CPU ignores it (and
         # warns), so only request it off-CPU.
         self._donate = jax.default_backend() != "cpu"
@@ -289,8 +275,9 @@ class ModelRunner:
             def prefill_fn(p, tokens, caches, chunked=False):
                 return prefill(p, tokens, caches, cfg, chunked=chunked)
 
-            def decode_fn(p, token, caches, batch_sharded=False):
-                return decode_step(p, token, caches, cfg)
+            def decode_fn(p, token, caches, batch_sharded=False,
+                          pages=None):
+                return decode_step(p, token, caches, cfg, pages=pages)
 
             return prefill_fn, decode_fn
         if self._sharded_fns is None:
@@ -416,22 +403,22 @@ class ModelRunner:
         def fn(params, tokens, pools, dense, block_table, lengths, slots,
                active, key):
             if in_place:
-                caches, pages = _take_pages(paged_decode_caches(
+                caches, pages = paged_decode_caches(
                     kv.specs, kv.group_count, pools, dense, block_table,
-                    lengths, slots, n_steps))
+                    lengths, slots, n_steps)
+                kw = {"pages": pages}
             else:
                 with jax.named_scope("gather"):
                     caches = build_view(kv.specs, kv.group_count, pools,
                                         dense, block_table, lengths, slots)
-                pages = {}
+                kw = {}
             _, decode_fn = self._model_fns(params)
 
             def body(carry, i):
                 toks, caches = carry
                 logits, caches = decode_fn(
-                    params, toks, _put_pages(caches, pages),
-                    batch_sharded=batch_sharded)
-                caches, _ = _take_pages(caches)
+                    params, toks, caches, batch_sharded=batch_sharded,
+                    **kw)
                 with jax.named_scope("sample"):
                     nxt = _sample(logits, jax.random.fold_in(key, i),
                                   temperature)
@@ -519,7 +506,6 @@ class ModelRunner:
                 nxt, logits, kv.pools, kv.dense, kv.lengths = self._call(
                     fn, (params, tokens, kv.pools, kv.dense, bt_row,
                          kv.lengths, jnp.int32(slot), key))
-                kv.host_lengths[slot] = S
             self._wait("runner.prefill", (nxt, logits))
             with tracing.span("runner.prefill.fetch"):
                 nxt = np.asarray(nxt)
@@ -541,7 +527,6 @@ class ModelRunner:
                 nxt, logits, kv.pools, kv.dense, kv.lengths = self._call(
                     fn, (params, tokens, kv.pools, kv.dense,
                          kv.block_table, kv.lengths, jnp.int32(slot), key))
-                kv.host_lengths[slot] += C
             self._wait("runner.prefill", (nxt, logits))
             with tracing.span("runner.prefill.fetch"):
                 nxt = np.asarray(nxt)
@@ -569,13 +554,9 @@ class ModelRunner:
             Bp <<= 1
         Bp = min(Bp, kv.max_slots) if Bp > len(act) else Bp
         fn = self._decode_fns.get((Bp, steps))
-        # `pages`: the pages the active rows occupy once the call is
-        # done, which the in-place path reads; 0 on the gathered path.
-        pages = sum(-(-(int(kv.host_lengths[s]) + steps) // kv.page_size)
-                    for s in act) if self.paged_decode else 0
         with tracing.span("runner.decode", rows=len(act), Bp=Bp,
                           steps=steps, first=fn is None,
-                          paged=self.paged_decode, pages=pages):
+                          paged=self.paged_decode) as attrs:
             with tracing.span("runner.decode.dispatch"):
                 pad = [s for s in range(kv.max_slots)
                        if s not in slot_tokens]
@@ -595,11 +576,17 @@ class ModelRunner:
                          jnp.asarray(np.asarray(tokens, np.int32)[:, None]),
                          kv.pools, kv.dense, kv.block_table, kv.lengths,
                          comp[0], comp[1], key))
-                kv.host_lengths[act] += steps
             self._wait("runner.decode", (nxt, logits))
             with tracing.span("runner.decode.fetch"):
                 nxt_h = np.asarray(nxt)          # (steps, Bp, 1)
                 logits_h = np.asarray(logits)    # (steps, Bp, V)
-                return {s: ([int(t) for t in nxt_h[:, i, 0]],
-                            logits_h[:, i])
-                        for i, s in enumerate(act)}
+                out = {s: ([int(t) for t in nxt_h[:, i, 0]],
+                           logits_h[:, i])
+                       for i, s in enumerate(act)}
+            if tracing.active():
+                # the pages the active rows occupy now the call is done,
+                # which the in-place path read; 0 on the gathered path
+                lens = np.asarray(kv.lengths)[act]
+                attrs["pages"] = int(np.sum(-(-lens // kv.page_size))) \
+                    if self.paged_decode else 0
+        return out

@@ -386,21 +386,25 @@ L_, N_, PS_, P_ = 2, 40, 32, 8
 CAP_ = P_ * PS_
 
 
-def _paged_inputs(steps, G, seed=0):
+# 8-bit words: VP(7,[1,-1])'s M + E = 8 (KVQ's words are 16 bits).
+KVQ8 = dataclasses.replace(KVQ, E=1)
+
+
+def _paged_inputs(steps, G, seed=0, KV=2, kvq=KVQ):
     """Pools, a shuffled block table and ragged rows: committed lengths
     on and off page boundaries, one at capacity - steps, and a parked
     padding row (all-dummy block table, nothing committed)."""
-    B, KV, dh = 6, 2, 32
+    B, dh = 6, 32
     rng = np.random.default_rng(seed)
     key = jax.random.PRNGKey(seed)
     k1, k2, k3, k4, kq = jax.random.split(key, 5)
     k_w, k_s = quantize_kv(
-        jax.random.normal(k1, (L_ * N_, PS_, KV, dh)) * 2.0, KVQ)
+        jax.random.normal(k1, (L_ * N_, PS_, KV, dh)) * 2.0, kvq)
     v_w, v_s = quantize_kv(jax.random.normal(k2, (L_ * N_, PS_, KV, dh)),
-                           KVQ)
+                           kvq)
     pools = [a.reshape((L_, N_) + a.shape[1:]) for a in (k_w, v_w, k_s, v_s)]
-    t_kw, t_ks = quantize_kv(jax.random.normal(k3, (B, steps, KV, dh)), KVQ)
-    t_vw, t_vs = quantize_kv(jax.random.normal(k4, (B, steps, KV, dh)), KVQ)
+    t_kw, t_ks = quantize_kv(jax.random.normal(k3, (B, steps, KV, dh)), kvq)
+    t_vw, t_vs = quantize_kv(jax.random.normal(k4, (B, steps, KV, dh)), kvq)
     base = np.array([1, PS_, PS_ + 1, 5 * PS_ - 1, CAP_ - steps, 0],
                     np.int32)
     fill = rng.integers(1, steps + 1, size=B).astype(np.int32)
@@ -430,18 +434,20 @@ def _poisoned(args):
             k_s.at[:, bad].set(jnp.nan), v_s.at[:, bad].set(jnp.nan), *rest)
 
 
-@pytest.mark.parametrize("steps,G,window", [
-    (1, 1, None),
-    (4, 2, None),
-    (1, 2, 40),
-    (4, 1, 70),
+@pytest.mark.parametrize("steps,G,window,KV,kvq", [
+    (1, 1, None, 2, KVQ),
+    (4, 2, None, 2, KVQ),
+    (1, 2, 40, 2, KVQ),
+    (4, 1, 70, 2, KVQ),
+    (1, 1, None, 4, KVQ8),      # 8-bit words, four heads a 32-bit row
+    (4, 2, 70, 8, KVQ8),
 ])
-def test_paged_decode_kernel_interpret_parity(steps, G, window):
+def test_paged_decode_kernel_interpret_parity(steps, G, window, KV, kvq):
     """The paged kernel body (interpreter) == the gather-plus-contiguous
     oracle, and reads no page outside a row's live span: poisoning the
     dummy page and the stale block-table entries changes no bit."""
-    _, vp = kv_cache_formats(KVQ)
-    args = _paged_inputs(steps, G)
+    _, vp = kv_cache_formats(kvq)
+    args = _paged_inputs(steps, G, KV=KV, kvq=kvq)
     want = kref.vp_paged_decode_attention_ref(*args, vp, window=window)
     got = ops.vp_paged_decode_attention(*args, vp, window=window,
                                         pages_per_block=4, interpret=True)
@@ -451,6 +457,37 @@ def test_paged_decode_kernel_interpret_parity(steps, G, window):
         *_poisoned(args), vp, window=window, pages_per_block=4,
         interpret=True)
     np.testing.assert_array_equal(np.asarray(poisoned), np.asarray(got))
+
+
+@pytest.mark.parametrize("backend,page,KV,dh,dtype,ok", [
+    ("native", 16, 8, 128, jnp.int16, True),    # qwen3-0.6b's cell
+    ("native", 16, 4, 128, jnp.int8, True),
+    ("native", 16, 2, 128, jnp.int8, False),    # heads share 32-bit rows
+    ("native", 16, 1, 128, jnp.int16, False),
+    ("native", 16, 2, 64, jnp.int16, False),    # half-lane heads
+    ("native", 16, 6, 128, jnp.int16, False),   # pads to 8 heads in HBM
+    ("native", 48, 8, 128, jnp.int16, False),   # scales miss 128 lanes
+    ("interpret", 16, 2, 64, jnp.int16, True),
+    ("interpret", 16, 2, 128, jnp.int8, False),
+    ("ref", 16, 2, 128, jnp.int8, True),        # gathers: any layout
+])
+def test_paged_decode_supported_layouts(backend, page, KV, dh, dtype, ok):
+    """The runner reads pages in place only in layouts the backend's
+    kernel tiles; the op refuses the others rather than drop heads."""
+    with substrate.force_backend(backend):
+        assert ops.paged_decode_supported(page, KV, dh, dtype) is ok
+    if backend == "interpret" and not ok:
+        pools = jnp.zeros((1, 3, page, KV, dh), dtype)
+        scales = jnp.ones((1, 3, page, 1, 1), jnp.float32)
+        tail = jnp.zeros((1, 1, KV, dh), dtype)
+        tail_s = jnp.ones((1, 1, 1, 1), jnp.float32)
+        with pytest.raises(ValueError, match="cannot tile"):
+            ops.vp_paged_decode_attention(
+                jnp.zeros((1, 1, KV, dh)), pools, pools, scales, scales,
+                tail, tail, tail_s, tail_s, jnp.int32(0),
+                jnp.zeros((1, 1), jnp.int32), jnp.zeros((1,), jnp.int32),
+                jnp.ones((1,), jnp.int32), kv_cache_formats(KVQ8)[1],
+                interpret=True)
 
 
 @pytest.mark.parametrize("steps", [1, 4])

@@ -293,21 +293,22 @@ def _decode_logits(params, cfg, in_place: bool, lookahead: int):
     return calls
 
 
-@pytest.mark.parametrize("arch,lookahead", [
-    ("qwen3-0.6b", 1),
-    ("qwen3-0.6b", 3),          # run-ahead: the in-flight tail
-    ("qwen3-moe-30b-a3b", 1),
-    ("zamba2-7b", 3),           # hybrid: SSM rows beside the pages
-    ("gemma3-27b", 1),          # dense local rings beside global pages
+@pytest.mark.parametrize("arch,lookahead,E", [
+    ("qwen3-0.6b", 1, 2),
+    ("qwen3-0.6b", 3, 2),       # run-ahead: the in-flight tail
+    ("qwen3-moe-30b-a3b", 1, 2),
+    ("zamba2-7b", 3, 2),        # hybrid: SSM rows beside the pages
+    ("gemma3-27b", 1, 2),       # dense local rings beside global pages
+    ("qwen2-0.5b", 3, 1),       # 8-bit words (in place on this backend)
 ])
-def test_paged_decode_matches_gathered_view(arch, lookahead):
+def test_paged_decode_matches_gathered_view(arch, lookahead, E):
     """Every model with paged packed layers reads its pages in place,
     and on the ref backend every decode call's tokens and logits are
     bit-identical to the gathered view's."""
     cfg = dataclasses.replace(
         registry.get_smoke_config(arch),
         quant=QuantConfig(mode="vp", quantize_kv_cache=True,
-                          kv_layout="packed"))
+                          kv_layout="packed", E=E))
     params = _params(cfg)
     got = _decode_logits(params, cfg, True, lookahead)
     want = _decode_logits(params, cfg, False, lookahead)
@@ -318,3 +319,23 @@ def test_paged_decode_matches_gathered_view(arch, lookahead):
             assert g[slot][0] == w[slot][0]
             if REF_BACKEND:
                 np.testing.assert_array_equal(g[slot][1], w[slot][1])
+
+
+@pytest.mark.parametrize("E,d_head,in_place", [
+    (2, 128, True),             # 16-bit words, 2 heads of 128 lanes
+    (1, 128, False),            # 8-bit words: 2 heads fill no 32-bit row
+    (2, None, False),           # 16-lane heads: Mosaic copies no page
+])
+def test_runner_reads_in_place_only_layouts_the_kernel_tiles(
+        E, d_head, in_place):
+    """On the chip's backend the runner keeps the gathered view for a
+    layout the paged kernel cannot tile, instead of reading it wrong."""
+    from repro.serving.runner import ModelRunner
+
+    cfg = dataclasses.replace(
+        registry.get_smoke_config("qwen3-0.6b"), d_head=d_head,
+        quant=QuantConfig(mode="vp", quantize_kv_cache=True,
+                          kv_layout="packed", E=E))
+    kv = PagedKVCache(cfg, max_slots=SLOTS, capacity=CAP, page_size=PAGE)
+    with substrate.force_backend("native"):
+        assert ModelRunner(cfg, kv).paged_decode is in_place

@@ -118,23 +118,34 @@ def test_equalizer_kernel_compiles(compile_native):
 LAYERS, PAGE, CAP, ROWS = 28, 16, 2048, 16
 
 
-@pytest.mark.parametrize("steps", [1, 4])
-def test_vp_paged_decode_attention_compiles(compile_native, steps):
-    _, vp = kv_cache_formats(QuantConfig(mode="vp", quantize_kv_cache=True))
+@pytest.mark.parametrize("steps,E,kv", [
+    (1, 2, KV),
+    (4, 2, KV),
+    # other layouts the runner reads in place (16- and 8-bit words)
+    (1, 2, 2),
+    (1, 2, 24),
+    (1, 1, 4),
+    (4, 1, 32),
+])
+def test_vp_paged_decode_attention_compiles(compile_native, steps, E, kv):
+    _, vp = kv_cache_formats(QuantConfig(mode="vp", quantize_kv_cache=True,
+                                         E=E))
+    with substrate.force_backend("native"):
+        assert ops.paged_decode_supported(PAGE, kv, DH, storage_dtype(vp))
     n_pages = 1 + ROWS * CAP // PAGE
-    words = ((LAYERS, n_pages, PAGE, KV, DH), storage_dtype(vp))
+    words = ((LAYERS, n_pages, PAGE, kv, DH), storage_dtype(vp))
     scales = ((LAYERS, n_pages, PAGE, 1, 1), jnp.float32)
-    tail = ((ROWS, steps, KV, DH), storage_dtype(vp))
+    tail = ((ROWS, steps, kv, DH), storage_dtype(vp))
     tail_s = ((ROWS, steps, 1, 1), jnp.float32)
     rows = ((ROWS,), jnp.int32)
     hlo = compile_native(
         lambda *a: ops.vp_paged_decode_attention(*a, vp),
-        ((ROWS, 1, H, DH), jnp.bfloat16), words, words, scales, scales,
-        tail, tail, tail_s, tail_s, ((), jnp.int32),
+        ((ROWS, 1, max(H, kv), DH), jnp.bfloat16), words, words, scales,
+        scales, tail, tail, tail_s, tail_s, ((), jnp.int32),
         ((ROWS, CAP // PAGE), jnp.int32), rows, rows)
     assert "tpu_custom_call" in hlo
     # the pools reach the kernel whole: no copy of a layer's words
-    assert f"[1,{n_pages},{PAGE},{KV},{DH}]" not in hlo
+    assert f"[1,{n_pages},{PAGE},{kv},{DH}]" not in hlo
 
 
 def _decode_program(one_chip, in_place: bool):
