@@ -22,8 +22,8 @@ One process runs every phase, in this order:
                  outside (b) may have resolved to a jnp oracle.
   (d) equalizer  the paper's Table-I B-VP design (B=64 antennas, U=8
                  users) equalizes 1024 channel realizations on the fused
-                 batched kernel, against the fake-quant model within
-                 rtol 2e-4.
+                 batched kernel, one product a lane, against the
+                 fake-quant model within rtol 2e-4.
 
 `--four-chips` runs only this: the same model served on a (data, model) =
 (1, 4) mesh, compared with the one-device engine on the same prompts.
@@ -299,6 +299,7 @@ def phase_equalizer(seed: int):
     import jax
     import numpy as np
 
+    from repro import tracing
     from repro.mimo import ChannelConfig, table1_specs
     from repro.mimo.equalizer import equalize_quantized
     from repro.mimo.mvm_engine import equalize_vp_kernel
@@ -307,13 +308,21 @@ def phase_equalizer(seed: int):
     ch = ChannelConfig()
     ens = make_ensemble(jax.random.PRNGKey(seed), ch, MVM_REALIZATIONS, 10.0)
     spec = {s.name: s for s in calibrate_specs(table1_specs(), ens)}["B-VP"]
-    s_kernel = np.asarray(jax.block_until_ready(equalize_vp_kernel(
-        spec, ens.w_beam, ens.y_beam, fused=True)))
+    tracing.enable()
+    try:
+        s_kernel = np.asarray(jax.block_until_ready(equalize_vp_kernel(
+            spec, ens.w_beam, ens.y_beam, fused=True)))
+    finally:
+        tracing.disable()
+    lanes = tracing.drain()[1].get("kernels.vp_quant_matmul_batched.lanes", 0)
+    check(lanes >= 1, "the (2U, B) x (B, 2) products did not take the "
+                      "batched kernel's lane body")
     with jax.default_matmul_precision("highest"):
         s_model = np.asarray(equalize_quantized(spec, ens.w_beam, ens.y_beam))
     err = np.abs(s_kernel - s_model)
     worst = float((err / (2e-4 + 2e-4 * np.abs(s_model))).max())
-    log(f"equalizer: B={ch.B} U={ch.U} n={MVM_REALIZATIONS}, max |err| "
+    log(f"equalizer: B={ch.B} U={ch.U} n={MVM_REALIZATIONS}, lane-body "
+        f"lowerings {lanes}, max |err| "
         f"{float(err.max()):.6g}, worst err / (2e-4 + 2e-4 |model|) "
         f"{worst:.6g} (must be <= 1)")
     check(worst <= 1.0, "equalizer kernel outside rtol 2e-4 of the model")

@@ -113,6 +113,22 @@ def _shard_shape(shape, shards):
                  for d, s in zip(shape, shards))
 
 
+def _lanes_bytes(gl: int, shape) -> Optional[int]:
+    """The lane body of the batched fused kernel
+    (`kernels/vp_quant_matmul._vp_quant_matmul_lanes_kernel`): `blocks`
+    is (gl,), products a step; `shape` (G, M, K, N).  Float a (M·Kp, gl)
+    and b (N·Kp, gl) in, (M·N, gl) out, K rounded up to 8 sublanes; b
+    quantized once and one quantized (Kp, gl) slab of a live at a time."""
+    if shape is None or len(shape) < 4:
+        return None
+    m, k, n = (int(d) for d in shape[-3:])
+    kp = -(-k // 8) * 8
+    in_bytes = (m * kp + n * kp) * gl * _F32
+    out = m * n * gl * _F32
+    temps = (n * kp + kp) * gl * _F32
+    return 2 * in_bytes + 2 * out + temps
+
+
 def kernel_vmem_bytes(
     kernel: str,
     blocks: Tuple[int, int, int],
@@ -134,6 +150,8 @@ def kernel_vmem_bytes(
     fits on-chip BECAUSE the mesh shrank the operand is admitted, and
     one whose per-shard tile still overflows is rejected.
     """
+    if kernel == "vp_quant_matmul_batched_lanes":
+        return _lanes_bytes(int(blocks[0]), shape)
     bm, bk, bn = int(blocks[0]), int(blocks[1]), int(blocks[2])
     if shards is not None and shape is not None:
         # Per-shard launch: the resolver re-clamps tiles to the LOCAL

@@ -47,7 +47,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.analysis import contracts
+from repro import tracing
+from repro.analysis import contracts, vmem
 from repro.core.formats import FXPFormat, VPFormat
 from repro.core import packing as pk
 from . import autotune, ref, substrate
@@ -63,7 +64,10 @@ from .vp_bwd_matmul import vp_matmul_dx_pallas, vp_matmul_dw_pallas
 from .vp_matmul import vp_matmul_pallas, vp_matmul_batched_pallas
 from .vp_block_matmul import block_vp_matmul_pallas
 from .vp_quant_matmul import (
+    LANE_BLOCK,
+    LANES,
     vp_quant_matmul_pallas,
+    vp_quant_matmul_batched_lanes_pallas,
     vp_quant_matmul_batched_pallas,
 )
 
@@ -749,6 +753,49 @@ def vp_matmul_batched(
     return out[:, :M, :N]
 
 
+def _lane_block(G: int, M: int, K: int, N: int, formats) -> Optional[int]:
+    """Products a step of the lane body for a (G, M, K) x (G, K, N)
+    batch, or None where each product keeps its own tile program.
+
+    Products narrower than an MXU tile (N or K under 128 lanes) ride the
+    lanes: `LANE_BLOCK` products a step, fewer where G is smaller (a
+    multiple of 128 still) or the VMEM model wants fewer; None where even
+    128 products a step overflow it.
+    """
+    if N >= LANES and K >= LANES:
+        return None
+    shape = (G, M, K, N)
+    gl = min(LANE_BLOCK, -(-G // LANES) * LANES)
+    while not vmem.vmem_feasible(
+            "vp_quant_matmul_batched_lanes", (gl,), formats, shape)[0]:
+        if gl == LANES:
+            return None
+        gl = max(LANES, gl // 2 // LANES * LANES)
+    return gl
+
+
+def _vp_quant_matmul_lanes(a, b, a_fxp, a_vp, b_fxp, b_vp, gl,
+                           interpret, out_dtype):
+    """(G, M, K) x (G, K, N) through the lane body: the batch moves to
+    the lanes, G rounds up to the lane block and K to 8 sublanes (zeros
+    quantize to zero and add nothing), and the (M·N, Gp) result moves
+    back.  The moves are plain XLA ops the compiler fuses into the
+    operands' producers and the result's consumer."""
+    G, M, K = a.shape
+    N = b.shape[-1]
+    Kp, Gp = -(-K // 8) * 8, -(-G // gl) * gl
+    with jax.named_scope("pad"):
+        at = jnp.pad(a, ((0, Gp - G), (0, 0), (0, Kp - K)))
+        at = at.transpose(1, 2, 0).reshape(M * Kp, Gp)
+        bt = jnp.pad(b, ((0, Gp - G), (0, Kp - K), (0, 0)))
+        bt = bt.transpose(2, 1, 0).reshape(N * Kp, Gp)
+    out = vp_quant_matmul_batched_lanes_pallas(
+        at, bt, a_fxp, a_vp, b_fxp, b_vp, m=M, n=N, lane_block=gl,
+        interpret=interpret, out_dtype=out_dtype)
+    with jax.named_scope("pad"):
+        return out[:, :G].reshape(M, N, G).transpose(2, 0, 1)
+
+
 def vp_quant_matmul_batched(
     a, b,
     a_fxp: FXPFormat, a_vp: VPFormat,
@@ -764,12 +811,27 @@ def vp_quant_matmul_batched(
     Numerically identical to `vp_quant` on each operand followed by
     `vp_matmul_batched`, with no quantized-plane HBM round-trip — ONE
     pallas_call for the whole batch.
+
+    Two bodies, chosen by the shape alone: products narrower than an MXU
+    tile (N or K under 128) whose lane block fits VMEM ride the lanes
+    (`_lane_block`); larger products, explicit `blocks` and CSPADE masks
+    (built on the tile grid) keep one tile program per product.  Each
+    kernel lowering counts in `kernels.vp_quant_matmul_batched.lanes` or
+    `.tiles` (`repro.tracing`).
     """
     contracts.require_quant_safe(a_fxp, a_vp, "vp_quant_matmul_batched")
     contracts.require_quant_safe(b_fxp, b_vp, "vp_quant_matmul_batched")
     G, M, K = a.shape
     _, _, N = b.shape
     backend = substrate.resolve_backend(interpret)
+    gl = None
+    if blocks is None and a_act is None:
+        gl = _lane_block(G, M, K, N, (a_fxp, a_vp, b_fxp, b_vp))
+    if gl is not None and backend != "ref":
+        tracing.count("kernels.vp_quant_matmul_batched.lanes")
+        return _vp_quant_matmul_lanes(
+            a, b, a_fxp, a_vp, b_fxp, b_vp, gl,
+            interpret=(backend == "interpret"), out_dtype=out_dtype)
     blocks = _resolve_blocks(
         "vp_quant_matmul_batched", (G, M, K, N),
         (a_fxp, a_vp, b_fxp, b_vp), backend, blocks, a_act)
@@ -778,6 +840,7 @@ def vp_quant_matmul_batched(
         return ref.vp_quant_matmul_batched_ref(
             a, b, a_fxp, a_vp, b_fxp, b_vp,
             a_act=a_act, b_act=b_act, tiles=blocks, out_dtype=out_dtype)
+    tracing.count("kernels.vp_quant_matmul_batched.tiles")
     bm, bk, bn = blocks
     with jax.named_scope("pad"):
         ap, bp = _pad3(a, bm, bk), _pad3(b, bk, bn)

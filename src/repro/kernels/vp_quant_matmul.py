@@ -21,6 +21,18 @@ CSPADE tile-activity masks work exactly as in `vp_matmul` (flat
 scalar-prefetch flags + `pl.when` skip).  Numerics are bit-identical to
 `vp_quant` -> `vp_matmul`, which is what tests/test_substrate_kernels.py
 asserts against the ref oracles.
+
+Batches of products smaller than one MXU tile (the equalizer's
+(2U, B) x (B, 2) = (16, 64) x (64, 2)) take a second batched body,
+`vp_quant_matmul_batched_lanes_pallas`: the batch rides the 128 lanes,
+one product a lane, and a grid step covers a block of `LANE_BLOCK`
+products.  The operands arrive as (M·K, G) and (N·K, G) planes, the
+cascade runs on them as on any tile, and each output row is K f32
+products summed over the sublanes on the VPU — the paper's MAC array,
+one lane a product.  Nothing is padded to a 128 x 128 tile: where the
+tile grid would run each (16, 64) x (64, 2) product as one 16 x 128 x
+128 MXU step, with b padded 64x in HBM, the lane body reads only the
+operands' own elements (and G rounded up to the lane block).
 """
 from __future__ import annotations
 
@@ -34,6 +46,8 @@ from repro.core.formats import FXPFormat, VPFormat
 from . import substrate as sub
 
 BM, BK, BN = 256, 256, 256
+LANES = 128            # the TPU's lane count
+LANE_BLOCK = 512       # products a grid step of the lane body, at most
 
 
 def _vp_quant_matmul_kernel(
@@ -143,6 +157,71 @@ def vp_quant_matmul_batched_pallas(
         dimension_semantics=semantics,
         interpret=interpret,
     )(*masks, a, b)
+
+
+def _vp_quant_matmul_lanes_kernel(
+    a_ref, b_ref, o_ref,
+    *, a_fxp: FXPFormat, a_vp: VPFormat, b_fxp: FXPFormat, b_vp: VPFormat,
+    m: int, k: int, n: int,
+):
+    """One lane block of products: a (m·k, gl), b (n·k, gl) -> (m·n, gl).
+
+    Row i·k + kk of a holds a[:, i, kk] and row j·k + kk of b holds
+    b[:, kk, j], one product a lane.  b is quantized once; each of the
+    m (k, gl) slabs of a is quantized in turn, multiplied by each of
+    the n slabs of b and summed over its k sublanes, in f32.
+    """
+    f32 = jnp.float32
+    b = sub.quantize_dequant_cascade(b_ref[...], b_fxp, b_vp, f32)
+    for i in range(m):
+        a = sub.quantize_dequant_cascade(
+            a_ref[i * k:(i + 1) * k, :], a_fxp, a_vp, f32)
+        for j in range(n):
+            r = i * n + j
+            o_ref[r:r + 1, :] = jnp.sum(
+                a * b[j * k:(j + 1) * k, :], axis=0,
+                keepdims=True).astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "a_fxp", "a_vp", "b_fxp", "b_vp", "m", "n", "lane_block",
+        "interpret", "out_dtype"),
+)
+def vp_quant_matmul_batched_lanes_pallas(
+    a, b,
+    a_fxp: FXPFormat, a_vp: VPFormat,
+    b_fxp: FXPFormat, b_vp: VPFormat,
+    m: int, n: int, lane_block: int,
+    interpret: bool = False,
+    out_dtype=jnp.float32,
+):
+    """Batched fused quantize+matmul with the batch on the lanes.
+
+    a (m·K, G) and b (n·K, G) floats, laid out as the lane kernel reads
+    them (row i·K + k of a is a[:, i, k], row j·K + k of b is
+    b[:, k, j]), give (m·n, G), row i·n + j the (i, j) output of each
+    product.  G is a multiple of `lane_block`, itself a multiple of 128;
+    K is a multiple of 8.  Same cascades and formats as the tile body.
+    """
+    rows_a, G = a.shape
+    k = rows_a // m
+    kernel = functools.partial(
+        _vp_quant_matmul_lanes_kernel,
+        a_fxp=a_fxp, a_vp=a_vp, b_fxp=b_fxp, b_vp=b_vp, m=m, k=k, n=n)
+    return sub.vp_pallas_call(
+        kernel,
+        grid=(G // lane_block,),
+        in_specs=[
+            pl.BlockSpec((m * k, lane_block), lambda g: (0, g)),
+            pl.BlockSpec((n * k, lane_block), lambda g: (0, g)),
+        ],
+        out_specs=pl.BlockSpec((m * n, lane_block), lambda g: (0, g)),
+        out_shape=jax.ShapeDtypeStruct((m * n, G), out_dtype),
+        dimension_semantics=("parallel",),
+        interpret=interpret,
+    )(a, b)
 
 
 @functools.partial(

@@ -5,13 +5,14 @@ VP-matmul kernels (Fig. 9c / Fig. 10 as TPU kernel calls).
 einsum — bit-identical values); this module runs the same computation
 through the actual kernel path.  Two execution modes:
 
-  * ``mode="batched"`` (default): the truly-batched grid.  Realization g
-    runs its OWN (2U, B) x (B, 2) tile program on the kernel's leading
-    batch grid dimension — the A operand stacks the W re/im planes along
-    rows and the B operand holds [y_re, y_im] as two columns, so ONE
-    pallas_call produces all four real products of the paper's 4-RM
-    complex-multiplier structure for every realization.  FLOPs are
-    8·n·U·B, independent of how many realizations ride along.
+  * ``mode="batched"`` (default): the truly-batched kernel.  Realization
+    g is its own (2U, B) x (B, 2) product — on the fused path one lane of
+    a block of products, on the unfused path one tile program of the
+    kernel's leading batch grid dimension — the A operand stacks the W
+    re/im planes along rows and the B operand holds [y_re, y_im] as two
+    columns, so ONE pallas_call produces all four real products of the
+    paper's 4-RM complex-multiplier structure for every realization.
+    FLOPs are 8·n·U·B, independent of how many realizations ride along.
 
   * ``mode="masked"`` (legacy, kept as the parity oracle): realizations
     are folded into a tall (n·U, B) x (B, n) matmul and the (row, col)
@@ -158,7 +159,7 @@ def batched_complex_mvm(
       out[:, U:, 0] = W_im y_re   out[:, U:, 1] = W_im y_im
     This is the entry point the wideband OFDM path folds subcarriers into
     (mimo/ofdm.py): anything expressible as a batch of complex MVMs rides
-    the same leading batch grid dimension.
+    the same batched kernel (on the fused path, one product a lane).
 
     `blocks=None` defers the tile choice to the autotuner (ops.py resolves
     a tuned cache entry, else the shape-clamped heuristic).  The mask-free

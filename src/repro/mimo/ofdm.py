@@ -222,17 +222,19 @@ def equalize_wideband(
     quantizer, so they fold into the operands).
 
     how="flat": fold (S, n) into one leading batch dim — ONE batched
-        kernel launch of S·n tile programs (the serving path).
+        kernel launch over all S·n products (the serving path).
     how="vmap": `jax.vmap` of the per-subcarrier batch over S (the
         autobatching path; identical numerics).
     how="shard_map": shard the subcarrier axis over `mesh`'s "sc" axis
         via `parallel.sharding.shard_over_subcarriers`, each device
         running the flat path on its slab (requires S % mesh size == 0).
 
-    `blocks=None` defers the kernel tiling to `kernels.autotune`
-    (persisted tuned entry when one exists, else the shape-clamped
-    heuristic — the MVM tile never pads beyond the (2U, B) x (B, 2)
-    operands).
+    `blocks=None` lets the kernel op choose: products narrower than an
+    MXU tile, as the (2U, B) x (B, 2) MVMs are below B = 128, ride the
+    fused kernel's lanes, one product a lane, padded only in their count
+    (`ops.vp_quant_matmul_batched`); larger ones, and the unfused path,
+    take `kernels.autotune`'s tiling, which on the chip pads each
+    product's lane axes to 128.  Explicit `blocks` pin the tile grid.
     """
     S, n, U, B = w.shape
     if len(specs) != S:

@@ -7,6 +7,7 @@ refusals surface here instead of on the chip.  Every compiled program
 must hold the Pallas kernel (`tpu_custom_call`).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -111,6 +112,24 @@ def test_equalizer_kernel_compiles(compile_native):
         lambda w, y: equalize_vp_kernel(spec, w, y, fused=True),
         ((n, U, B), jnp.complex64), ((n, B), jnp.complex64))
     assert "tpu_custom_call" in hlo
+
+
+def test_equalizer_lanes_compile_unpadded(compile_native):
+    """A 400 MHz slot's 44,352 (16, 64) x (64, 2) B-VP products ride the
+    lanes: the lane body compiles, and no operand is padded to a
+    (..., 128, 128) f32 tile on its way to the kernel."""
+    from repro.core import FXPFormat, VPFormat
+
+    w_fxp, w_vp = FXPFormat(12, 11), VPFormat(7, (11, 9, 7, 6))
+    y_fxp, y_vp = FXPFormat(9, 1), VPFormat(7, (1, -1))
+    G = 44352
+    hlo = compile_native(
+        lambda a, b: ops.vp_quant_matmul_batched(
+            a, b, w_fxp, w_vp, y_fxp, y_vp),
+        ((G, 16, 64), jnp.float32), ((G, 64, 2), jnp.float32))
+    assert "tpu_custom_call" in hlo
+    assert "vp_quant_matmul_batched_lanes_pallas" in hlo
+    assert not re.search(r"f32\[[0-9,]*128,128\]", hlo)
 
 
 # The serving cell's paged cache: 28 layers of pools, pages of 16,
